@@ -29,18 +29,31 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import jax
 import numpy as np
 
-# TPU-class table value (paper Table 1 adaptation): used whenever the
-# runtime can't report a real accelerator core count (CPU containers).
+# CPU containers report no grid-parallelism capacity; the advisor's fill
+# priors were set against this TPU-class table value (paper Table 1
+# adaptation), so CPU runs keep it and test behaviour stays stable.
 DEFAULT_N_CORES = 256
+
+# MXUs per chip by ``device_kind`` — each MXU consumes one 128x128 output
+# tile at a time, the unit ``execution.grid_tiles`` counts. Google Cloud
+# TPU system-architecture pages: v4 and v5p have 2 TensorCores x 4 MXUs,
+# v5e has 1 TensorCore x 4 MXUs.
+TPU_MXUS = {
+    "TPU v4": 8,
+    "TPU v5": 8,
+    "TPU v5p": 8,
+    "TPU v5 lite": 4,
+    "TPU v5e": 4,
+}
 
 
 def detect_core_count(default: int = DEFAULT_N_CORES) -> int:
     """Grid-parallelism capacity of the attached accelerator(s).
 
-    Precedence: ``REPRO_N_CORES`` env override > summed per-device core
-    count from ``jax.devices()`` (accelerators only) > ``default``. CPU
-    devices report no meaningful MXU-slot count, so a CPU-only container
-    keeps the TPU-class table value — test and CI behavior is stable.
+    Precedence: ``REPRO_N_CORES`` env override > ``default`` on a CPU
+    backend > the summed per-chip MXU count (:data:`TPU_MXUS`, or a GPU's
+    ``core_count``). An accelerator whose capacity is unknown raises: a
+    guessed count would skew every fill-denominated threshold.
     """
     env = os.environ.get("REPRO_N_CORES")
     if env:
@@ -59,24 +72,19 @@ def detect_core_count(default: int = DEFAULT_N_CORES) -> int:
                 f"ignoring the override and falling back to "
                 f"detection/default",
                 RuntimeWarning, stacklevel=2)
-    try:
-        devices = jax.devices()
-    except Exception:  # noqa: BLE001 — no backend at all
+    devices = jax.devices()
+    if devices[0].platform == "cpu":
         return default
     total = 0
-    reported = False
     for d in devices:
-        if getattr(d, "platform", "cpu") == "cpu":
-            return default
-        per = getattr(d, "num_cores", None) or getattr(d, "core_count", None)
-        if per:
-            reported = True
-            total += int(per)
-    # Accelerators that expose no core-count attribute (TPU devices often
-    # don't) keep the table default: a device *count* of 1-8 is not a
-    # grid-parallelism capacity, and fill-denominated thresholds scaled
-    # by it would be meaningless.
-    return total if reported else default
+        per = TPU_MXUS.get(d.device_kind) if d.platform == "tpu" \
+            else getattr(d, "core_count", None)
+        if not per:
+            raise ValueError(
+                f"no core count known for {d.platform} device kind "
+                f"{d.device_kind!r}; add it to TPU_MXUS or set REPRO_N_CORES")
+        total += int(per)
+    return total
 
 
 # ---------------------------------------------------------------------------
@@ -239,7 +247,11 @@ class ExecutionLane:
     is what lets two lanes' work genuinely overlap. A lane given a
     ``tracer`` (duck-typed ``repro.runtime.telemetry.Tracer``) records one
     ``dispatch`` event per dispatch so overlap decisions are attributable
-    after the fact."""
+    after the fact.
+
+    ``handles`` holds the lane's un-joined dispatches; a handle joined
+    elsewhere is dropped at the next dispatch, so a long-lived lane does
+    not keep every past result (a serving step's whole KV cache) alive."""
 
     def __init__(self, name: str = "lane0", *, index: int = 0, tracer=None):
         self.name = name
@@ -255,6 +267,7 @@ class ExecutionLane:
                        label=label or getattr(thunk, "__name__", "thunk"),
                        result=result, dispatch_t=t0,
                        overlap_group=overlap_group)
+        self.handles = [old for old in self.handles if not old.done]
         self.handles.append(h)
         if self.tracer is not None:
             self.tracer.record("dispatch", lane=self.name,
@@ -263,7 +276,9 @@ class ExecutionLane:
         return h
 
     def join_all(self) -> List[Any]:
-        return [h.join() for h in self.handles]
+        out = [h.join() for h in self.handles]
+        self.handles.clear()
+        return out
 
     def reset(self) -> None:
         self.handles.clear()

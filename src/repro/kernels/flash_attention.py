@@ -10,7 +10,9 @@ Layout: q (B, h, Sq, hd); k/v (B, kvh, Skv, hd) — GQA resolved by the
 BlockSpec index map (query head h reads kv head h // group).
 
 grid = (B, h, Sq/bq, Skv/bk), kv innermost; m/l/acc live in VMEM scratch
-across the kv sweep. Causal blocks above the diagonal are masked; fully
+across the kv sweep. m and l are kept 2-D, ``(bq, 128)`` with the value
+repeated across lanes, because Mosaic cannot lay out a 1-D row statistic
+broadcast back over the block. Causal blocks above the diagonal are masked; fully
 masked blocks are skipped via ``pl.when`` (no MXU pass issued).
 """
 from __future__ import annotations
@@ -27,6 +29,7 @@ NEG_INF = -1e30
 
 DEFAULT_BQ = 512
 DEFAULT_BK = 512
+LANES = 128
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -53,13 +56,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             qi = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             ki = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
             s = jnp.where(qi >= ki, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where((m_new > NEG_INF / 2)[:, None], p, 0.0)
+        m_prev = m_ref[...]                                  # (bq, LANES)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_col = m_new[:, :1]                                 # (bq, 1)
+        p = jnp.exp(s - m_col)
+        p = jnp.where(m_col > NEG_INF / 2, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * alpha[:, :1]
                         + jax.lax.dot_general(
                             p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -67,8 +71,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(j == k_steps - 1)
     def _store():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk",
@@ -102,8 +106,8 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, hh, i, j: (b, hh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, h, sq, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),        # m
-            pltpu.VMEM((bq,), jnp.float32),        # l
+            pltpu.VMEM((bq, LANES), jnp.float32),  # m
+            pltpu.VMEM((bq, LANES), jnp.float32),  # l
             pltpu.VMEM((bq, hd), jnp.float32),     # acc
         ],
         interpret=interpret,

@@ -1,7 +1,7 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` auto-detects the backend: on CPU (this container) the kernel
-body executes through the Pallas interpreter — bit-accurate control flow,
+``interpret`` follows :func:`repro.kernels.registry.interpret_mode`: on
+the CPU the kernel body executes through the Pallas interpreter — bit-accurate control flow,
 same BlockSpec tiling — while on TPU the same call lowers through Mosaic.
 Model code calls these via ``RuntimeCfg.use_pallas``.
 """
@@ -16,10 +16,7 @@ from repro.core import fp8 as fp8lib
 from repro.kernels import flash_attention as fa
 from repro.kernels import fp8_matmul as fm
 from repro.kernels import sparse24_matmul as sm
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.registry import interpret_mode as _interpret
 
 
 def fp8_matmul(x_q: jax.Array, w_q: jax.Array, x_inv_scale=1.0,

@@ -18,8 +18,11 @@ Registered backends:
   ``ref``             pure-f32 oracles (numerics ground truth)
   ``jnp``             XLA ``dot_general`` paths (CPU/TPU default)
   ``pallas``          Pallas TPU kernels; on CPU the same BlockSpec tiling
-                      executes through the interpreter (``interpret=True``),
-                      and shapes that cannot tile fall back to ``jnp``
+                      executes through the interpreter (``interpret=True``).
+                      M is zero-padded to a multiple of 8, so any row count
+                      runs the kernel; a K/N that cannot tile falls back to
+                      ``jnp`` with a warning and a count
+                      (:func:`fallback_count`)
   ``pallas_sparse24`` Pallas with the packed-2:4 kernel as the *primary*
                       path: its ``dense`` entry prunes + packs the weight
                       on the fly (serving-style, no STE)
@@ -29,9 +32,11 @@ override the block shapes (``None`` → kernel defaults / autotune cache).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
+import warnings
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -106,8 +111,32 @@ def available_backends() -> Tuple[str, ...]:
 
 
 def interpret_mode() -> bool:
-    """Pallas interpret fallback: everywhere except a real TPU."""
-    return jax.default_backend() != "tpu"
+    """Run Pallas kernels through the interpreter: on the CPU only."""
+    return jax.default_backend() == "cpu"
+
+
+# (entry, M, K, N) -> trace-time count of pallas entries that ran the jnp
+# path because a K/N block could not tile.
+_FALLBACKS: "collections.Counter[Tuple[str, int, int, int]]" = \
+    collections.Counter()
+
+
+def _fallback(entry: str, m: int, k: int, n: int) -> None:
+    key = (entry, m, k, n)
+    if key not in _FALLBACKS:
+        warnings.warn(
+            f"pallas {entry} {m}x{k}x{n}: K/N blocks cannot tile; running "
+            f"the jnp path instead", RuntimeWarning, stacklevel=3)
+    _FALLBACKS[key] += 1
+
+
+def fallback_count() -> int:
+    """Pallas entries traced onto the jnp path since the last reset."""
+    return sum(_FALLBACKS.values())
+
+
+def reset_fallbacks() -> None:
+    _FALLBACKS.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -133,6 +162,17 @@ def _tileable(*blocks: int) -> bool:
     return all(b % 8 == 0 for b in blocks)
 
 
+def _round8(m: int) -> int:
+    return -(-m // 8) * 8
+
+
+def _pad_rows(x2: jax.Array, mp: int) -> jax.Array:
+    """Zero-pad (M, K) to (mp, K): decode M is the slot count, often not
+    a multiple of the kernel's 8-row tile. Zero rows add nothing."""
+    m = x2.shape[0]
+    return x2 if mp == m else jnp.pad(x2, ((0, mp - m), (0, 0)))
+
+
 # ---------------------------------------------------------------------------
 # ref — exact-f32 oracles
 # ---------------------------------------------------------------------------
@@ -140,7 +180,8 @@ def _tileable(*blocks: int) -> bool:
 def _f32_dot(a: jax.Array, b: jax.Array) -> jax.Array:
     return jax.lax.dot_general(
         a.astype(jnp.float32), b.astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
 
 
 def _ref_dense(x, w, *, out_dtype=jnp.bfloat16, bm=None, bn=None, bk=None):
@@ -245,15 +286,18 @@ def _fwd_with_ref_grad(pallas_fn: Callable, ref_fn: Callable, *operands):
 def _pallas_dense(x, w, *, out_dtype=jnp.bfloat16, bm=None, bn=None, bk=None):
     x2, lead = _flatten_lead(x)
     (M, K), N = x2.shape, w.shape[-1]
-    fbm, fbn, fbk = _pallas_blocks(M, K, N, bm, bn, bk,
+    Mp = _round8(M)
+    fbm, fbn, fbk = _pallas_blocks(Mp, K, N, bm, bn, bk,
                                    fm.DEFAULT_BM, fm.DEFAULT_BN, fm.DEFAULT_BK)
     if not _tileable(fbm, fbn, fbk):
+        _fallback("dense", M, K, N)
         return _jnp_dense(x, w, out_dtype=out_dtype)
 
     def kernel(x2, w):
-        acc = fm.fp8_matmul_pallas(x2, w, bm=fbm, bn=fbn, bk=fbk,
+        acc = fm.fp8_matmul_pallas(_pad_rows(x2, Mp), w,
+                                   bm=fbm, bn=fbn, bk=fbk,
                                    interpret=interpret_mode())
-        return acc.astype(out_dtype)
+        return acc[:M].astype(out_dtype)
 
     out = _fwd_with_ref_grad(
         kernel, lambda a, b: _jnp_dense(a, b, out_dtype=out_dtype), x2, w)
@@ -263,17 +307,20 @@ def _pallas_dense(x, w, *, out_dtype=jnp.bfloat16, bm=None, bn=None, bk=None):
 def _pallas_fp8(x, w, *, out_dtype=jnp.bfloat16, bm=None, bn=None, bk=None):
     x2, lead = _flatten_lead(x)
     (M, K), N = x2.shape, w.shape[-1]
-    fbm, fbn, fbk = _pallas_blocks(M, K, N, bm, bn, bk,
+    Mp = _round8(M)
+    fbm, fbn, fbk = _pallas_blocks(Mp, K, N, bm, bn, bk,
                                    fm.DEFAULT_BM, fm.DEFAULT_BN, fm.DEFAULT_BK)
     if not _tileable(fbm, fbn, fbk):
+        _fallback("fp8", M, K, N)
         return _jnp_fp8(x, w, out_dtype=out_dtype)
 
     def kernel(x2, w):
         xq, xinv = fp8lib.quantize_weight_static(x2)
         wq, winv = fp8lib.quantize_weight_static(w)
-        acc = fm.fp8_matmul_pallas(xq, wq, bm=fbm, bn=fbn, bk=fbk,
+        acc = fm.fp8_matmul_pallas(_pad_rows(xq, Mp), wq,
+                                   bm=fbm, bn=fbn, bk=fbk,
                                    interpret=interpret_mode())
-        return (acc * (xinv * winv)).astype(out_dtype)
+        return (acc[:M] * (xinv * winv)).astype(out_dtype)
 
     out = _fwd_with_ref_grad(
         kernel, lambda a, b: _jnp_fp8(a, b, out_dtype=out_dtype), x2, w)
@@ -284,13 +331,16 @@ def _pallas_fp8_qdot(x_q, w_q, x_inv_scale=1.0, w_inv_scale=1.0, *,
                      out_dtype=jnp.float32, bm=None, bn=None, bk=None):
     x2, lead = _flatten_lead(x_q)
     (M, K), N = x2.shape, w_q.shape[-1]
-    fbm, fbn, fbk = _pallas_blocks(M, K, N, bm, bn, bk,
+    Mp = _round8(M)
+    fbm, fbn, fbk = _pallas_blocks(Mp, K, N, bm, bn, bk,
                                    fm.DEFAULT_BM, fm.DEFAULT_BN, fm.DEFAULT_BK)
     if not _tileable(fbm, fbn, fbk):
+        _fallback("fp8_qdot", M, K, N)
         return _jnp_fp8_qdot(x_q, w_q, x_inv_scale, w_inv_scale,
                              out_dtype=out_dtype)
-    acc = fm.fp8_matmul_pallas(x2, w_q, bm=fbm, bn=fbn, bk=fbk,
-                               interpret=interpret_mode())
+    acc = fm.fp8_matmul_pallas(_pad_rows(x2, Mp), w_q,
+                               bm=fbm, bn=fbn, bk=fbk,
+                               interpret=interpret_mode())[:M]
     return (acc * (x_inv_scale * w_inv_scale)) \
         .astype(out_dtype).reshape(*lead, N)
 
@@ -299,16 +349,19 @@ def _pallas_sparse24(x, values, meta, *, out_dtype=jnp.bfloat16,
                      bm=None, bn=None, bk=None):
     x2, lead = _flatten_lead(x)
     (M, K), N = x2.shape, values.shape[-1]
-    fbm, fbn, fbk = _pallas_blocks(M, K, N, bm, bn, bk,
+    Mp = _round8(M)
+    fbm, fbn, fbk = _pallas_blocks(Mp, K, N, bm, bn, bk,
                                    sm.DEFAULT_BM, sm.DEFAULT_BN, sm.DEFAULT_BK)
-    if not _tileable(fbm, fbn, fbk) or fbk % 8:
+    lanes_ok = fbn % sm.LANES == 0 or fbn == N < sm.LANES
+    if not _tileable(fbm, fbn, fbk) or fbk % 64 or not lanes_ok:
+        _fallback("sparse24", M, K, N)
         return _ref_sparse24(x, values, meta, out_dtype=out_dtype)
 
     def kernel(x2, values, meta):
-        return sm.sparse24_matmul_pallas(x2, values, meta,
+        return sm.sparse24_matmul_pallas(_pad_rows(x2, Mp), values, meta,
                                          bm=fbm, bn=fbn, bk=fbk,
                                          out_dtype=out_dtype,
-                                         interpret=interpret_mode())
+                                         interpret=interpret_mode())[:M]
 
     out = _fwd_with_ref_grad(
         kernel,

@@ -10,8 +10,15 @@ That converts directly to speedup exactly where LLM serving is
 weight-bandwidth-bound (decode) — the TPU version of the paper's
 "context-dependent sparsity benefit".
 
-Decompression per block (pure VPU ops, no gather):
-  meta byte -> four 2-bit positions -> one-hot (2, 4) per group -> sum.
+Decompression per block (pure VPU ops, no gather). Packed row ``4q + j``
+holds slot ``j % 2`` of group ``2q + j // 2``, and field ``j`` of meta row
+``q`` is its in-group position. For each 128-lane chunk the values are
+widened to f32 in a scratch buffer, so rows ``4q + j`` come out of one
+strided load per ``j``; a compare-and-select per position ``p`` gives the
+dense rows ``8q + c`` (``c = 4 * (j // 2) + p``) as eight ``(bk/8, 128)``
+slabs. The slabs are stored c-major within every 64-row chunk, and ``x``'s
+columns are permuted the same way outside the kernel, so the MXU contracts
+the decompressed block without any sublane shuffle in VMEM.
 """
 from __future__ import annotations
 
@@ -26,36 +33,43 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BM = 128
 DEFAULT_BN = 256
 DEFAULT_BK = 256          # K-block of the *dense* K dimension
+LANES = 128
 
 
-def _decompress_block(vals, meta, bk: int, bn: int):
-    """vals: (bk/2, bn); meta: (bk/8, bn) uint8 -> dense (bk, bn) f32."""
-    # unpack 4 × 2-bit positions per byte -> (bk/2, bn) int32 in 0..3
-    p0 = (meta & 0x3).astype(jnp.int32)
-    p1 = ((meta >> 2) & 0x3).astype(jnp.int32)
-    p2 = ((meta >> 4) & 0x3).astype(jnp.int32)
-    p3 = ((meta >> 6) & 0x3).astype(jnp.int32)
-    # interleave to (bk/2, bn): groups are consecutive pairs
-    idx = jnp.stack([p0, p1, p2, p3], axis=1).reshape(bk // 2, bn)
-    v = vals.astype(jnp.float32).reshape(bk // 4, 2, bn)
-    ix = idx.reshape(bk // 4, 2, bn)
-    # scatter two values into their 4-slot group via one-hot compare
-    slots = jax.lax.broadcasted_iota(jnp.int32, (bk // 4, 2, 4, bn), 2)
-    onehot = (ix[:, :, None, :] == slots).astype(jnp.float32)
-    dense = jnp.sum(v[:, :, None, :] * onehot, axis=1)        # (bk/4, 4, bn)
-    return dense.reshape(bk, bn)
+def permute_k(x: jax.Array) -> jax.Array:
+    """Column order the kernel's decompressed block uses: within every 64
+    columns, ``x[:, 64u + 8q + c]`` moves to ``64u + 8c + q``."""
+    m, k = x.shape
+    return x.reshape(m, k // 64, 8, 8).swapaxes(2, 3).reshape(m, k)
 
 
-def _sparse24_kernel(x_ref, v_ref, m_ref, o_ref, acc_ref, *,
+def _sparse24_kernel(x_ref, v_ref, m_ref, o_ref, acc_ref, w_ref, vs_ref, *,
                      k_steps: int, bk: int, bn: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w_block = _decompress_block(v_ref[...], m_ref[...], bk, bn)  # VPU
-    x = x_ref[...].astype(jnp.float32)
+    rows = bk // 8
+    cw = min(LANES, bn)
+    for n0 in range(0, bn, cw):                                   # VPU
+        lanes = slice(n0, n0 + cw)
+        vs_ref[...] = v_ref[:, lanes].astype(jnp.float32)
+        meta = m_ref[:, lanes].astype(jnp.int32)   # Mosaic shifts need i32
+        for h in range(2):
+            ja, jb = 2 * h, 2 * h + 1
+            va = vs_ref[pl.ds(ja, rows, stride=4), :]
+            vb = vs_ref[pl.ds(jb, rows, stride=4), :]
+            ia = (meta >> (2 * ja)) & 3
+            ib = (meta >> (2 * jb)) & 3
+            for p in range(4):
+                c = 4 * h + p
+                slab = jnp.where(ia == p, va, 0.0) + jnp.where(ib == p, vb, 0.0)
+                w_ref[:, 8 * c:8 * c + 8, lanes] = slab.reshape(
+                    bk // 64, 8, cw)
+    x = x_ref[...]
+    w = w_ref[...].reshape(bk, bn).astype(x.dtype)  # exact: bf16/fp8 values
     acc_ref[...] += jax.lax.dot_general(                          # MXU
-        x, w_block, (((1,), (0,)), ((), ())),
+        x, w, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
@@ -69,15 +83,19 @@ def sparse24_matmul_pallas(x: jax.Array, values: jax.Array, meta: jax.Array,
                            *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                            bk: int = DEFAULT_BK, out_dtype=jnp.bfloat16,
                            interpret: bool = False) -> jax.Array:
-    """x: (M, K); values: (K/2, N); meta: (K/8, N) uint8 → (M, N)."""
+    """x: (M, K); values: (K/2, N); meta: (K/8, N) uint8 → (M, N).
+
+    Needs ``bk % 64 == 0``, and ``bn % 128 == 0`` or ``bn < 128``."""
     M, K = x.shape
     K2, N = values.shape
     assert K == 2 * K2, (x.shape, values.shape)
     assert meta.shape == (K // 8, N), meta.shape
     bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
     assert M % bm == 0 and N % bn == 0 and K % bk == 0
-    assert bk % 8 == 0
+    assert bk % 64 == 0 and (bn % LANES == 0 or bn < LANES), (bk, bn)
     k_steps = K // bk
+    if x.dtype != jnp.bfloat16:
+        x = x.astype(jnp.float32)
 
     return pl.pallas_call(
         functools.partial(_sparse24_kernel, k_steps=k_steps, bk=bk, bn=bn),
@@ -89,9 +107,11 @@ def sparse24_matmul_pallas(x: jax.Array, values: jax.Array, meta: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
+                        pltpu.VMEM((bk // 64, 64, bn), jnp.float32),
+                        pltpu.VMEM((bk // 2, min(LANES, bn)), jnp.float32)],
         interpret=interpret,
-    )(x, values, meta)
+    )(permute_k(x), values, meta)
 
 
 # ---------------------------------------------------------------------------

@@ -29,9 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# persistent compilation cache: sweep re-runs and hillclimb iterations skip
-# recompiles of unchanged cells
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
+# persistent compilation cache (directory: launch/compile_cache.py): sweep
+# re-runs and hillclimb iterations skip recompiles of unchanged cells
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
 
 from repro.configs import (
@@ -449,4 +448,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     raise SystemExit(main())

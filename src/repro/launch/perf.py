@@ -29,7 +29,6 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
 
 from repro.configs import get_arch, get_shape
@@ -169,4 +168,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     raise SystemExit(main())

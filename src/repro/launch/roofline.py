@@ -322,4 +322,6 @@ def min_bytes_estimate(cfg, shape) -> float:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     main()

@@ -196,4 +196,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     raise SystemExit(main())

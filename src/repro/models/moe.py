@@ -127,8 +127,11 @@ def moe_mlp(x: jax.Array, p: Dict[str, jax.Array], cfg: ArchConfig,
     # dispatch einsum output then reshards to expert-parallel layout — GSPMD
     # emits the canonical MoE all-to-all between the two constraints.
     xt = shard_tag(rt, x.reshape(G, gs, d), "moe_tokens")
+    # HIGHEST: at the TPU's default precision an f32 dot rounds its
+    # operands to bf16, and the router's top-k is what that would perturb
     logits = jnp.einsum("gsd,de->gse", xt.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
 
     if rt.moe_gather_dispatch:
         token_idx, weight, aux = gather_dispatch(logits, cfg, cap)

@@ -301,12 +301,12 @@ def _paged_clear_slot(pat, caches, slot, phys):
     return jax.tree_util.tree_map_with_path(clear, caches)
 
 
-def _paged_take_slot(pat, caches, slot, page_ids):
+def _paged_take_slot(pat, caches, slot, idx):
     """Gather one slot's state for export: paged leaves as the slot's
     pages-in-use only (n_super, n_used, page_size, ...), dense leaves as
-    the slot row. Unjitted — handoffs are rare and variable-sized."""
+    the slot row. ``idx`` is the (n_used,) int32 page-id array. Unjitted —
+    handoffs are rare and variable-sized."""
     paged = _paged_blocks(pat)
-    idx = jnp.asarray(page_ids, jnp.int32)
 
     def take(path, f):
         if _is_paged_leaf(path, paged):
@@ -315,12 +315,11 @@ def _paged_take_slot(pat, caches, slot, page_ids):
     return jax.tree_util.tree_map_with_path(take, caches)
 
 
-def _paged_put_slot(pat, caches, state, slot, page_ids):
+def _paged_put_slot(pat, caches, state, slot, idx):
     """Scatter an exported slot's state into freshly allocated pages
     (paged leaves) and the slot row (dense leaves) — the receiving half
-    of an O(pages) handoff."""
+    of an O(pages) handoff. ``idx`` is the (n_used,) int32 page-id array."""
     paged = _paged_blocks(pat)
-    idx = jnp.asarray(page_ids, jnp.int32)
 
     def put(path, f, s):
         if _is_paged_leaf(path, paged):
@@ -361,6 +360,10 @@ class ServeSession:
     ``submit``/``step``/``run`` drive a single FIFO queue; the multi-tenant
     scheduler (:mod:`repro.runtime.scheduler`) instead calls the slot-level
     API directly: ``has_free_slot`` → ``admit(req)`` → ``decode_once()``.
+
+    ``device`` pins the session's serving state (caches, page map, token
+    buffer, positions, RNG) to one device, the one its ``params`` live on;
+    ``None`` leaves it on JAX's default device.
     """
 
     def __init__(self, params, cfg: ArchConfig, *, batch_slots: int,
@@ -369,7 +372,9 @@ class ServeSession:
                  policy=None, auto_backend: Optional[str] = None,
                  verbose_policy: bool = False, telemetry=None,
                  paged: bool = False, page_size: int = 16,
-                 pages: Optional[int] = None, speculative=None):
+                 pages: Optional[int] = None, speculative=None,
+                 device=None):
+        self.device = device
         # Speculative decoding rides on the greedy-exactness contract:
         # the verify pass accepts drafts by argmax comparison, so a
         # sampling session has no exact acceptance rule. Refuse up front
@@ -434,9 +439,10 @@ class ServeSession:
             self.pager = paging.PageAllocator(
                 self.pages, self.page_size, mp, batch_slots,
                 state_block_tokens=paging.state_block_tokens(cfg))
-            self.caches = init_paged_cache(cfg, batch_slots, max_len,
-                                           self.page_size, self.pages)
-            self._page_map = jnp.asarray(self.pager.page_map())
+            with self._on_device():
+                self.caches = self._put(init_paged_cache(
+                    cfg, batch_slots, max_len, self.page_size, self.pages))
+            self._page_map = self._put(self.pager.page_map())
             self.step_fn = _cached_jit(
                 "serve_paged",
                 lambda: make_paged_serve_step(cfg, rt, temperature),
@@ -444,7 +450,8 @@ class ServeSession:
         else:
             self.page_size, self.pages = 0, 0
             self.pager = None
-            self.caches = init_cache(cfg, batch_slots, max_len)
+            with self._on_device():
+                self.caches = self._put(init_cache(cfg, batch_slots, max_len))
             self.step_fn = _cached_jit(
                 "serve", lambda: make_serve_step(cfg, rt, temperature),
                 cfg, rt, temperature, ambient)
@@ -453,8 +460,8 @@ class ServeSession:
         self.slot_pos = np.zeros((batch_slots,), np.int32)
         self.prefill_fn = _cached_jit(
             "prefill", lambda: make_prefill_step(cfg, rt), cfg, rt, ambient)
-        self.rng = jax.random.PRNGKey(seed)
-        self.tokens = jnp.zeros((batch_slots, 1), jnp.int32)
+        self.rng = self._put(jax.random.PRNGKey(seed))
+        self.tokens = self._put(np.zeros((batch_slots, 1), np.int32))
         self.queue: List[Request] = []
         self.completed: List[Request] = []
         self._inflight: Optional[DecodeTicket] = None
@@ -478,6 +485,19 @@ class ServeSession:
                 self._draft_params = raw_params
             if self.speculative.adaptive:
                 self.adaptive_k = spv.AdaptiveK(self.speculative)
+
+    # -- device placement ---------------------------------------------------
+    def _on_device(self):
+        """Create arrays directly on the session's device."""
+        if self.device is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self.device)
+
+    def _put(self, x):
+        """Host values (or a pytree of arrays) onto the session's device."""
+        if self.device is None:
+            return jax.tree_util.tree_map(jnp.asarray, x)
+        return jax.device_put(x, self.device)
 
     # -- slot-level API (used by the scheduler) ----------------------------
     def _policy_scope(self):
@@ -523,10 +543,10 @@ class ServeSession:
         trash = self.pages                        # pool row past the last page
         out = np.full((mp,), trash, np.int32)
         out[:len(page_ids)] = page_ids
-        return jnp.asarray(out)
+        return self._put(out)
 
     def _sync_page_map(self) -> None:
-        self._page_map = jnp.asarray(self.pager.page_map())
+        self._page_map = self._put(self.pager.page_map())
 
     def admit(self, req: Request) -> int:
         """Bulk-prefill ``req`` into a free slot and sample its first
@@ -544,7 +564,7 @@ class ServeSession:
             # the first decode write at position lp. Raises PagesExhausted
             # (admission refused) — callers gate on can_admit() first.
             page_ids = self.pager.alloc_slot(slot, lp + 1)
-        prompt = jnp.asarray(np.asarray(req.prompt, np.int32))[None, :]
+        prompt = self._put(np.asarray(req.prompt, np.int32)[None, :])
         t0 = time.perf_counter()
         with self._policy_scope():
             logits, pcaches = self.prefill_fn(self.params, prompt)
@@ -606,7 +626,8 @@ class ServeSession:
         # session buffers: these are fresh arrays, not views.
         if self.paged:
             page_ids = self.pager.slot_pages(slot)
-            state = _paged_take_slot(self._pat, self.caches, slot, page_ids)
+            state = _paged_take_slot(self._pat, self.caches, slot,
+                                     self._put(np.asarray(page_ids, np.int32)))
             out = SlotExport(request=req, caches=state,
                              pos=int(self.slot_pos[slot]),
                              token=int(self.tokens[slot, 0]),
@@ -653,6 +674,9 @@ class ServeSession:
         slot = next((i for i, s in enumerate(self.slots) if s is None), None)
         if slot is None:
             raise RuntimeError("import_slot() with no free slot")
+        # a handoff between partitions on different chips moves the slot
+        # state onto this session's device before it is scattered in
+        state = self._put(export.caches)
         if self.paged != bool(export.pages or export.page_size):
             raise ValueError(
                 "cache layout mismatch: paged and dense sessions cannot "
@@ -675,8 +699,7 @@ class ServeSession:
                               if _is_paged_leaf(path, paged_blocks)
                               else s.shape)
                 return f
-            jax.tree_util.tree_map_with_path(collect, self.caches,
-                                             export.caches)
+            jax.tree_util.tree_map_with_path(collect, self.caches, state)
             if ours != theirs:
                 raise ValueError(
                     "cache layout mismatch: the exporting session's slot "
@@ -686,8 +709,9 @@ class ServeSession:
             # can_accept_handoff() first.
             page_ids = self.pager.import_slot(slot, export.pages,
                                               export.pos + 1)
-            self.caches = _paged_put_slot(self._pat, self.caches,
-                                          export.caches, slot, page_ids)
+            self.caches = _paged_put_slot(
+                self._pat, self.caches, state, slot,
+                self._put(np.asarray(page_ids, np.int32)))
             self._sync_page_map()
             self.pager.record(self.tracer, phase="import", slot=slot,
                               tenant=export.request.tenant or "",
@@ -695,15 +719,13 @@ class ServeSession:
         else:
             ours = [f.shape[:1] + f.shape[2:]
                     for f in jax.tree_util.tree_leaves(self.caches)]
-            theirs = [s.shape
-                      for s in jax.tree_util.tree_leaves(export.caches)]
+            theirs = [s.shape for s in jax.tree_util.tree_leaves(state)]
             if ours != theirs:
                 raise ValueError(
                     "cache layout mismatch: the exporting session's slot "
                     "state does not fit this session (same cfg and max_len "
                     "required for a live handoff)")
-            self.caches = _restore_slot_cache(self.caches, export.caches,
-                                              slot)
+            self.caches = _restore_slot_cache(self.caches, state, slot)
         self.slots[slot] = export.request
         self.slot_pos[slot] = export.pos
         self.tokens = self.tokens.at[slot, 0].set(export.token)
@@ -825,14 +847,14 @@ class ServeSession:
         if lane is None:
             lane = cc.ExecutionLane("session")
         t0 = time.perf_counter()
-        posv = jnp.asarray(self.slot_pos)
+        posv = self._put(self.slot_pos)
         if k > 1:
             # draft on its own lane; the verify thunk consumes the draft
             # handle's *future* tokens (an XLA data dependency — the host
             # never materializes draft tokens), so a caller that
             # dispatches the next draft before joining this verify gets
             # draft(n+1)/verify(n) overlap on real async hardware.
-            active = jnp.asarray(
+            active = self._put(
                 np.array([s is not None for s in self.slots], np.bool_))
             draft_fn, verify_fn = self._spec_fns_for(k)
             draft_lane = cc.ExecutionLane("draft", tracer=self.tracer)

@@ -46,6 +46,8 @@ import dataclasses
 import json
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
+
 from repro.core import concurrency as cc
 from repro.core import execution as ex
 from repro.core.speculative import SpecDecodeSpec
@@ -89,13 +91,7 @@ def make_partitions(n: int, devices: Optional[Sequence] = None
     which is what the behavioral contracts test."""
     if n <= 0:
         raise ValueError("need at least one partition")
-    if devices is None:
-        import jax
-        try:
-            devices = tuple(jax.devices())
-        except Exception:  # noqa: BLE001 — no backend: logical partitions
-            devices = ()
-    devices = tuple(devices)
+    devices = tuple(jax.devices() if devices is None else devices)
     if len(devices) < n:
         return [DevicePartition(index=i, devices=devices, logical=True)
                 for i in range(n)]
@@ -541,13 +537,15 @@ class ServingRuntime:
             p_pages = pspec.pages if pspec.pages is not None else spec.pages
             p_spec = spec.speculative if pspec.speculative is None \
                 else pspec.speculative
+            device = self._device(part)
             sess = ServeSession(
-                self._place_params(use_params, part), cfg,
+                use_params if device is None
+                else jax.device_put(use_params, device), cfg,
                 batch_slots=pspec.batch_slots or spec.batch_slots,
                 max_len=spec.max_len, temperature=spec.temperature,
                 seed=spec.seed, policy=pol, telemetry=tr,
                 paged=p_paged, page_size=p_psize, pages=p_pages,
-                speculative=p_spec, **kw)
+                speculative=p_spec, device=device, **kw)
             sched = StreamScheduler(
                 sess, admission=pspec.admission, tracer=tr,
                 quota=self._quota_for(quota, pspec, i))
@@ -622,14 +620,14 @@ class ServingRuntime:
         return pspec.quota
 
     @staticmethod
-    def _place_params(params, part: DevicePartition):
-        """Pin the model replica to the partition's lead device. Logical
-        partitions (single-device fallback) share the original params —
-        duplicating them would only waste the one device's memory."""
+    def _device(part: DevicePartition):
+        """The partition's lead device: its session keeps the model replica
+        and all serving state there. Logical partitions (single-device
+        fallback) get ``None`` — they share the original params and the
+        default device, since duplicating would only waste its memory."""
         if part.logical or not part.devices:
-            return params
-        import jax
-        return jax.device_put(params, part.devices[0])
+            return None
+        return part.devices[0]
 
     def policy_key(self, i: int) -> str:
         """The partition's *resolved* execution-policy identity — live

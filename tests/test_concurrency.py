@@ -140,6 +140,19 @@ def test_lane_handle_join_and_timing():
     assert lane.join_all() == [out]
 
 
+def test_lane_keeps_only_unjoined_handles():
+    """A long-lived lane (one per serving partition) must not pin every
+    past result: joined handles drop out at the next dispatch."""
+    lane = cc.ExecutionLane("l0")
+    for i in range(5):
+        lane.dispatch(lambda i=i: jnp.full((4,), i), label="step").join()
+    assert len(lane.handles) == 1
+    pending = lane.dispatch(lambda: jnp.ones(()), label="open")
+    assert lane.handles == [pending]
+    assert [float(v) for v in lane.join_all()] == [1.0]
+    assert lane.handles == []
+
+
 def test_lane_dispatch_returns_before_join():
     """Dispatch enqueues; the handle is not ready until joined."""
     lane = cc.ExecutionLane("l0")

@@ -251,6 +251,33 @@ def test_manual_migration_mid_request_token_equality(model):
     assert phases.count("done") == 2
 
 
+@pytest.mark.parametrize("paged", [False, True])
+def test_device_partitions_pin_state_and_hand_off(model, paged):
+    """Partitions bound to a device keep params and all serving state
+    there, and a mid-request handoff puts the slot on the importer's
+    device — still token-for-token equal to the solo run."""
+    from repro.runtime.server import DevicePartition
+    cfg, _ = model
+    dev = jax.devices()[0]
+    parts = [DevicePartition(i, (dev,)) for i in range(2)]
+    rt = _runtime(model, _spec(paged=paged, page_size=8), partitions=parts)
+    rt.add_tenant("mover", partition=0)
+    reqs = _requests(cfg, 0, n=2, max_new=10)
+    for r in reqs:
+        rt.submit("mover", r)
+    for _ in range(3):
+        rt.step()
+    assert rt.migrate("mover", 1).slots_handed_off == 2
+    while not all(r.done for r in reqs):
+        rt.step()
+    assert [r.out for r in reqs] == _solo_outputs(model, reqs)
+    for sess in rt.sessions:
+        assert sess.device == dev
+        for tree in (sess.params, sess.caches, sess.tokens, sess.rng):
+            for leaf in jax.tree_util.tree_leaves(tree):
+                assert leaf.devices() == {dev} and leaf.committed
+
+
 def test_migration_drains_under_load(model):
     """With no free slot on the target, the handoff defers: the in-flight
     request keeps decoding on the (frozen) source and crosses over only
