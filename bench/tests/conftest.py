@@ -1,0 +1,35 @@
+"""Shared fixtures: a tiny benchmark directory that runs on the CPU."""
+import shutil
+import sys
+from pathlib import Path
+
+import pytest
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT / "src", ROOT):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
+
+DATA = Path(__file__).resolve().parent / "data"
+BENCH = ROOT / "bench"
+
+E2E = [("ttft_p90_ms", "ms"), ("itl_p95_ms", "ms"),
+       ("output_tokens_per_s", "tokens/s"), ("setup_s", "s")]
+
+
+@pytest.fixture
+def tiny_bench(tmp_path):
+    """(bench dir, cell) for a two-layer MoE under a light mix."""
+    root = tmp_path / "bench"
+    (root / "configs").mkdir(parents=True)
+    (root / "traffic").mkdir()
+    shutil.copytree(BENCH / "metrics", root / "metrics")
+    shutil.copytree(BENCH / "references", root / "references")
+    shutil.copy(DATA / "tiny.json", root / "configs" / "tiny.json")
+    shutil.copy(DATA / "tiny-traffic.json", root / "traffic" / "tiny.json")
+    names = sorted(p.stem for p in (BENCH / "metrics").glob("*.py"))
+    cell = {"name": "tiny.chat", "config": "tiny", "traffic": "tiny",
+            "chips": 1,
+            "end_to_end": [{"name": n, "unit": u} for n, u in E2E],
+            "per_layer": [{"name": n, "unit": "x"} for n in names]}
+    return root, cell
