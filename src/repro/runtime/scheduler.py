@@ -53,6 +53,7 @@ import numpy as np
 from repro.core import concurrency as cc
 from repro.core import execution as ex
 from repro.runtime.serve_loop import Request, ServeSession
+from repro.runtime.telemetry import span
 
 ADMISSION_POLICIES = ("fifo", "round_robin", "fair_quantum")
 QUOTA_POLICIES = ("static", "adaptive")
@@ -783,8 +784,9 @@ class StreamScheduler:
         co-dispatch heterogeneous partitions before joining any of them."""
         if self._t0 is None:
             self._t0 = time.perf_counter()
-        self.quota.on_step(self)
-        self._admit_free_slots()
+        with span(self.tracer, "sched.admit", step=self.step_count):
+            self.quota.on_step(self)
+            self._admit_free_slots()
         return self.session.dispatch_decode(lane,
                                             overlap_group=overlap_group)
 
@@ -792,30 +794,31 @@ class StreamScheduler:
         """Join half of one scheduler step: block on the ticket, then the
         same per-tenant accounting as the synchronous path."""
         done = self.session.join_decode(ticket)
-        self.step_count += 1
-        for t in self.tenants.values():
-            if t.active:
-                t.service_steps += 1
-        drain = getattr(self.session, "drain_spec_deltas", None)
-        if drain is not None:
-            for tenant, drafted, accepted in drain():
-                t = self.tenants.get(tenant)
-                if t is None:
-                    continue
-                t.spec_steps += 1
-                t.spec_drafted += drafted
-                t.spec_accepted += accepted
-        for req in done:
-            t = self.tenants[req.tenant]
-            t.active -= 1
-            self._finish(t, req)
-            if (t.active == 0 and not t.queue
-                    and getattr(self.session, "adaptive_k", None)
-                    is not None):
-                # a drained tenant must stop constraining the batch-wide
-                # adaptive speculation depth
-                self.session.adaptive_k.forget(t.tenant_id)
-        self._wall_s = time.perf_counter() - self._t0
+        with span(self.tracer, "sched.finish", step=self.step_count):
+            self.step_count += 1
+            for t in self.tenants.values():
+                if t.active:
+                    t.service_steps += 1
+            drain = getattr(self.session, "drain_spec_deltas", None)
+            if drain is not None:
+                for tenant, drafted, accepted in drain():
+                    t = self.tenants.get(tenant)
+                    if t is None:
+                        continue
+                    t.spec_steps += 1
+                    t.spec_drafted += drafted
+                    t.spec_accepted += accepted
+            for req in done:
+                t = self.tenants[req.tenant]
+                t.active -= 1
+                self._finish(t, req)
+                if (t.active == 0 and not t.queue
+                        and getattr(self.session, "adaptive_k", None)
+                        is not None):
+                    # a drained tenant must stop constraining the batch-wide
+                    # adaptive speculation depth
+                    self.session.adaptive_k.forget(t.tenant_id)
+            self._wall_s = time.perf_counter() - self._t0
         return done
 
     def step(self) -> List[Request]:

@@ -33,6 +33,7 @@ from repro.models import (
     PAGED_KINDS, decode_step, init_cache, init_paged_cache, prefill)
 from repro.models.transformer import paged_decode_step
 from repro.models.layers import RuntimeCfg, DEFAULT_RT
+from repro.runtime.telemetry import span
 
 
 def make_prefill_step(cfg: ArchConfig, rt: RuntimeCfg = DEFAULT_RT,
@@ -553,6 +554,10 @@ class ServeSession:
         output token from the prefill logits. Active slots do not step —
         admission can never drop another request's tokens. Returns the
         slot index (the request may already be done if ``max_new == 1``)."""
+        with span(self.tracer, "session.admit", uid=req.uid):
+            return self._admit(req)
+
+    def _admit(self, req: Request) -> int:
         slot = next((i for i, s in enumerate(self.slots) if s is None), None)
         if slot is None:
             raise RuntimeError("admit() with no free slot")
@@ -565,16 +570,19 @@ class ServeSession:
             # (admission refused) — callers gate on can_admit() first.
             page_ids = self.pager.alloc_slot(slot, lp + 1)
         prompt = self._put(np.asarray(req.prompt, np.int32)[None, :])
-        t0 = time.perf_counter()
-        with self._policy_scope():
-            logits, pcaches = self.prefill_fn(self.params, prompt)
+        with span(self.tracer, "session.prefill.wait"):
+            t0 = time.perf_counter()
+            with self._policy_scope():
+                logits, pcaches = self.prefill_fn(self.params, prompt)
+            if self.tracer is not None:
+                jax.block_until_ready(logits)
+            wall_s = time.perf_counter() - t0
         if self.tracer is not None:
-            jax.block_until_ready(logits)
             self.tracer.record(
                 "prefill", m=lp, k=self.cfg.d_model, n=self.cfg.d_ff,
                 precision=self.cfg.precision, **self._policy_tag(),
-                wall_s=time.perf_counter() - t0,
-                tenant=req.tenant or "", meta={"uid": req.uid, "slot": slot})
+                wall_s=wall_s, tenant=req.tenant or "",
+                meta={"uid": req.uid, "slot": slot})
         if self.paged:
             self.caches = _paged_write_prompt(
                 self._pat, self.caches, pcaches, slot,
@@ -584,12 +592,13 @@ class ServeSession:
                               tenant=req.tenant or "", uid=req.uid)
         else:
             self.caches = _write_slot_cache(self.caches, pcaches, slot)
-        if self.temperature > 0:
-            self.rng, sub = jax.random.split(self.rng)
-            tok = int(jax.random.categorical(
-                sub, logits[0] / self.temperature))
-        else:
-            tok = int(jnp.argmax(logits[0]))
+        with span(self.tracer, "session.first_token.wait"):
+            if self.temperature > 0:
+                self.rng, sub = jax.random.split(self.rng)
+                tok = int(jax.random.categorical(
+                    sub, logits[0] / self.temperature))
+            else:
+                tok = int(jnp.argmax(logits[0]))
         self.slots[slot] = req
         self.slot_pos[slot] = lp
         self.tokens = self.tokens.at[slot, 0].set(tok)
@@ -791,6 +800,11 @@ class ServeSession:
         positions, completions) is only touched by :meth:`join_decode` —
         so the token stream is byte-identical to the synchronous path
         regardless of what other lanes do in between."""
+        with span(self.tracer, "session.dispatch"):
+            return self._dispatch_decode(lane, overlap_group)
+
+    def _dispatch_decode(self, lane: Optional[cc.ExecutionLane],
+                         overlap_group: int) -> DecodeTicket:
         if self._inflight is not None:
             raise RuntimeError(
                 "decode already in flight: join_decode the previous "
@@ -800,47 +814,8 @@ class ServeSession:
         k = self._next_spec_k()
         oom_done: List[Request] = []
         if self.paged:
-            if k > 1:
-                # batch-wide feasibility first: a k-deep verify needs a
-                # page for every candidate position. If the pool cannot
-                # cover the whole batch, downgrade THIS step to plain
-                # decode (k=1) instead of truncating requests that plain
-                # decode could still serve.
-                need = 0
-                for i, req in enumerate(self.slots):
-                    if req is None:
-                        continue
-                    tgt = min(int(self.slot_pos[i]) + k, self.max_len)
-                    need += max(0, self.pager.pages_for(tgt)
-                                - len(self.pager.slot_pages(i)))
-                if need > self.pager.free_pages:
-                    self.pager.record(self.tracer, phase="spec_downgrade",
-                                      need_pages=need)
-                    k = 1
-            # lazy page append: make sure every active slot has a page
-            # for each position this step may write (k candidates on a
-            # speculative step; positions past max_len route to the
-            # trash page in-kernel). Pool exhaustion finishes the
-            # request truncated (refused, never crashed).
-            for i, req in enumerate(self.slots):
-                if req is None:
-                    continue
-                need = min(int(self.slot_pos[i]) + k, self.max_len) \
-                    if k > 1 else int(self.slot_pos[i]) + 1
-                if self.pager.pages_for(need) > \
-                        len(self.pager.slot_pages(i)):
-                    try:
-                        self.pager.extend_slot(i, need)
-                        self._sync_page_map()
-                    except paging.PagesExhausted:
-                        self.pager.record(self.tracer, phase="page_oom",
-                                          slot=i, tenant=req.tenant or "",
-                                          uid=req.uid)
-                        req.done = True
-                        req.finish_t = time.perf_counter()
-                        self.completed.append(req)
-                        self.free_slot(i)
-                        oom_done.append(req)
+            with span(self.tracer, "session.pages"):
+                k = self._reserve_pages(k, oom_done)
             if self.n_active == 0:
                 return DecodeTicket(handle=None, oom_done=oom_done)
         self.rng, sub = jax.random.split(self.rng)
@@ -908,6 +883,55 @@ class ServeSession:
         self._inflight = ticket
         return ticket
 
+    def _reserve_pages(self, k: int, oom_done: List[Request]) -> int:
+        """Page bookkeeping before a decode step: every active slot gets
+        a page for each position the step may write. Returns the step's
+        speculative depth (downgraded to 1 when the pool cannot cover a
+        k-deep verify); requests refused a page are finished into
+        ``oom_done``."""
+        if k > 1:
+            # batch-wide feasibility first: a k-deep verify needs a
+            # page for every candidate position. If the pool cannot
+            # cover the whole batch, downgrade THIS step to plain
+            # decode (k=1) instead of truncating requests that plain
+            # decode could still serve.
+            need = 0
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                tgt = min(int(self.slot_pos[i]) + k, self.max_len)
+                need += max(0, self.pager.pages_for(tgt)
+                            - len(self.pager.slot_pages(i)))
+            if need > self.pager.free_pages:
+                self.pager.record(self.tracer, phase="spec_downgrade",
+                                  need_pages=need)
+                k = 1
+        # lazy page append: make sure every active slot has a page
+        # for each position this step may write (k candidates on a
+        # speculative step; positions past max_len route to the
+        # trash page in-kernel). Pool exhaustion finishes the
+        # request truncated (refused, never crashed).
+        for i, req in enumerate(self.slots):
+            if req is None:
+                continue
+            need = min(int(self.slot_pos[i]) + k, self.max_len) \
+                if k > 1 else int(self.slot_pos[i]) + 1
+            if self.pager.pages_for(need) > \
+                    len(self.pager.slot_pages(i)):
+                try:
+                    self.pager.extend_slot(i, need)
+                    self._sync_page_map()
+                except paging.PagesExhausted:
+                    self.pager.record(self.tracer, phase="page_oom",
+                                      slot=i, tenant=req.tenant or "",
+                                      uid=req.uid)
+                    req.done = True
+                    req.finish_t = time.perf_counter()
+                    self.completed.append(req)
+                    self.free_slot(i)
+                    oom_done.append(req)
+        return k
+
     def join_decode(self, ticket: DecodeTicket) -> List[Request]:
         """Join half of a decode step: block on the ticket's result, then
         run the host-side token accounting exactly as the synchronous path
@@ -918,34 +942,34 @@ class ServeSession:
             return list(ticket.oom_done)
         if ticket.spec_k > 1:
             return self._join_spec(ticket)
-        nxt = ticket.handle.join()[0]
-        nxt_np = np.asarray(nxt[:, 0])       # forces the step to complete
-        if self.tracer is not None:
-            self.tracer.record(
-                "decode", m=self.batch_slots, k=self.cfg.d_model,
-                n=self.cfg.d_ff, precision=self.cfg.precision,
-                **self._policy_tag(),
-                wall_s=time.perf_counter() - ticket.t0,
-                lane=ticket.lane, overlap_group=ticket.overlap_group,
-                meta={"n_active": self.n_active,
-                      "dispatch_to_ready_s":
-                          ticket.handle.dispatch_to_ready_s})
-        self.tokens = nxt
-        done = list(ticket.oom_done)
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            self.slot_pos[i] += 1
-            tok = int(nxt_np[i])
-            req.out.append(tok)
-            if self._maybe_finish(i, tok):
-                done.append(req)
-            elif self.paged:
-                # utilization accounting: positions written so far plus
-                # the pending next write
-                self.pager.note_tokens(i, int(self.slot_pos[i]) + 1)
-        if self.adaptive_k is not None:
-            self.adaptive_k.on_step()
+        with span(self.tracer, "session.decode.wait"):
+            nxt = ticket.handle.join()[0]
+            nxt_np = np.asarray(nxt[:, 0])   # forces the step to complete
+            ready = time.perf_counter()
+        with span(self.tracer, "session.accounting"):
+            if self.tracer is not None:
+                self.tracer.record(
+                    "decode", m=self.batch_slots, k=self.cfg.d_model,
+                    n=self.cfg.d_ff, precision=self.cfg.precision,
+                    **self._policy_tag(), wall_s=ready - ticket.t0,
+                    lane=ticket.lane, overlap_group=ticket.overlap_group,
+                    meta={"n_active": self.n_active})
+            self.tokens = nxt
+            done = list(ticket.oom_done)
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                self.slot_pos[i] += 1
+                tok = int(nxt_np[i])
+                req.out.append(tok)
+                if self._maybe_finish(i, tok):
+                    done.append(req)
+                elif self.paged:
+                    # utilization accounting: positions written so far
+                    # plus the pending next write
+                    self.pager.note_tokens(i, int(self.slot_pos[i]) + 1)
+            if self.adaptive_k is not None:
+                self.adaptive_k.on_step()
         return done
 
     def _join_spec(self, ticket: DecodeTicket) -> List[Request]:
@@ -953,20 +977,26 @@ class ServeSession:
         (plus the verify's own token) per slot, record acceptance
         telemetry, and — paged — trim the candidate pages the verify
         already scrubbed back to the free list."""
-        nxt, greedy, n_acc, _ = ticket.handle.join()
-        g_np = np.asarray(greedy)            # forces the step to complete
-        acc_np = np.asarray(n_acc)
+        with span(self.tracer, "session.decode.wait"):
+            nxt, greedy, n_acc, _ = ticket.handle.join()
+            g_np = np.asarray(greedy)        # forces the step to complete
+            acc_np = np.asarray(n_acc)
+            ready = time.perf_counter()
+        with span(self.tracer, "session.accounting"):
+            return self._commit_spec(ticket, nxt, g_np, acc_np, ready)
+
+    def _commit_spec(self, ticket: DecodeTicket, nxt, g_np: np.ndarray,
+                     acc_np: np.ndarray, ready: float) -> List[Request]:
+        """The host half of :meth:`_join_spec`, on the joined step's
+        greedy tokens and accepted counts."""
         k = ticket.spec_k
         if self.tracer is not None:
             self.tracer.record(
                 "decode", m=self.batch_slots, k=self.cfg.d_model,
                 n=self.cfg.d_ff, precision=self.cfg.precision,
-                **self._policy_tag(),
-                wall_s=time.perf_counter() - ticket.t0,
+                **self._policy_tag(), wall_s=ready - ticket.t0,
                 lane=ticket.lane, overlap_group=ticket.overlap_group,
-                meta={"n_active": self.n_active, "spec_k": k,
-                      "dispatch_to_ready_s":
-                          ticket.handle.dispatch_to_ready_s})
+                meta={"n_active": self.n_active, "spec_k": k})
         self.tokens = nxt
         done = list(ticket.oom_done)
         trimmed = False

@@ -715,19 +715,22 @@ class ServingRuntime:
         :meth:`_step_lanes`: planner-paired partitions dispatch through
         their lanes before any join, so heterogeneous partitions genuinely
         execute concurrently. Per-partition state transitions are
-        identical either way — only wall-clock overlap differs."""
-        if self.spec.overlap and self.n_partitions > 1:
-            done = self._step_lanes()
-        else:
-            done = []
-            for sched in self.schedulers:
-                done.extend(sched.step())
-        self.step_count += 1
-        self._advance_migrations()
-        if self.spec.migration.enabled:
-            self._maybe_migrate()
-        if self.controller is not None:
-            self.controller.on_step(self)
+        identical either way — only wall-clock overlap differs. The round
+        is one ``runtime.step`` span on the first partition's tracer."""
+        with telemetry.span(self.tracers[0], "runtime.step",
+                            step=self.step_count):
+            if self.spec.overlap and self.n_partitions > 1:
+                done = self._step_lanes()
+            else:
+                done = []
+                for sched in self.schedulers:
+                    done.extend(sched.step())
+            self.step_count += 1
+            self._advance_migrations()
+            if self.spec.migration.enabled:
+                self._maybe_migrate()
+            if self.controller is not None:
+                self.controller.on_step(self)
         return done
 
     def _overlap_candidates(self) -> List[ex.OverlapCandidate]:

@@ -14,11 +14,23 @@ half of the closed loop (the acting half is
   the observed GEMMs), per-shape latency EMAs, per-tenant request counts
   and p50/p99, fairness/overlap over tenants.
 
+* :func:`span` — a timed region of host work. Each span lands twice: as
+  a ``repro.<name>`` ``jax.profiler.TraceAnnotation`` (on the profiler's
+  host plane, on the same clock as the device's operations) and, on exit,
+  as one ``span`` event (``t`` = end, ``wall_s`` = duration, ``meta`` =
+  name, enclosing span's name, request uid) that is counted and sent to
+  the sinks but kept out of the ring, so the window the program's own
+  control loops sample covers as many steps as without spans. A span
+  whose name ends in ``.wait`` covers the host blocked on a device->host
+  read; nothing else carries that suffix.
+
 Producers: ``core/execution.matmul``/``resolve_policy`` (trace-time shape
 and policy events), ``core/concurrency.characterize_streams`` (per-stream
 wall times), ``runtime/scheduler.StreamScheduler`` (admission + request
-completion per tenant), ``ServeSession`` (prefill/decode wall times), and
-``runtime/train_loop``/``launch/train.py`` (per-step wall times).
+completion per tenant; ``sched.admit`` / ``sched.finish`` spans),
+``ServeSession`` (prefill/decode wall times; ``session.*`` spans),
+``ServingRuntime.step`` (the ``runtime.step`` span around each round),
+and ``runtime/train_loop``/``launch/train.py`` (per-step wall times).
 
 An *ambient* tracer can be installed with :func:`set_tracer` so deep call
 sites (every ``dense()`` in the model stack) need no plumbing; harness
@@ -34,8 +46,12 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import concurrency as cc
+
+# Profiler-trace names of spans: ``repro.<span name>``.
+SPAN_PREFIX = "repro."
 
 # One unit of grid parallelism (mirrors execution.MXU_TILE without the
 # import cycle: execution lazily consults this module's ambient tracer).
@@ -133,18 +149,20 @@ class Tracer:
         self._ingest(ev)
         return ev
 
-    def _ingest(self, ev: Event) -> None:
+    def _ingest(self, ev: Event, ring: bool = True) -> None:
         """Fold one already-built event in: ring append + every counter,
         all under the lock (concurrent emitters — multi-partition steps,
         ``run_async_dispatch`` threads — may interleave). Eviction past
         ``capacity`` is *counted* (per evicted kind) and warned about once:
         the sample views silently narrowing to a truncated window while
         the monotonic counters keep the true totals is exactly the
-        observability gap the dropped counters close."""
+        observability gap the dropped counters close. With ``ring=False``
+        the event is counted and sent to the sinks only."""
         with self._lock:
-            evicted = self._ring[0] if len(self._ring) == self.capacity \
-                else None
-            self._ring.append(ev)
+            evicted = self._ring[0] if ring and \
+                len(self._ring) == self.capacity else None
+            if ring:
+                self._ring.append(ev)
             if evicted is not None:
                 self._dropped[evicted.kind] = \
                     self._dropped.get(evicted.kind, 0) + 1
@@ -492,6 +510,75 @@ class Tracer:
             lines.append("  partitions: " + " ".join(
                 f"p{p}:{c}" for p, c in sorted(parts.items())))
         return "\n".join(lines)
+
+
+# ---------------------------------------------------------------------------
+# Spans
+# ---------------------------------------------------------------------------
+
+# Open span names, per thread, shared by every tracer: a span's parent may
+# record on another tracer, or on none.
+_SPAN_STACK = threading.local()
+
+
+def _span_stack() -> List[str]:
+    stack = getattr(_SPAN_STACK, "names", None)
+    if stack is None:
+        stack = _SPAN_STACK.names = []
+    return stack
+
+
+class Span:
+    """One timed region of host work (``with span(tracer, "x"): ...``).
+
+    Entering opens ``jax.profiler.TraceAnnotation("repro.<name>")``: with
+    a profiler trace running, the span sits on its host plane on the
+    device's clock, so the trace can say what the host did while the
+    device was idle. The annotation takes no per-call arguments (they
+    cost time even with the profiler off). Exiting sends one ``span``
+    event to ``tracer``'s counters and sinks, not its ring (nothing
+    without a tracer): ``t`` the end, ``wall_s`` the duration, ``step``
+    from ``fields``, and in ``meta`` the ``name``, the enclosing span's
+    name (``parent``, "" at the top; the stack is per thread) and the
+    other ``fields`` (such as the request ``uid``)."""
+
+    __slots__ = ("tracer", "name", "fields", "_ann", "_t0", "_parent")
+
+    def __init__(self, tracer: Optional[Tracer], name: str,
+                 fields: Dict[str, Any]):
+        self.tracer = tracer
+        self.name = name
+        self.fields = fields
+
+    def __enter__(self) -> "Span":
+        stack = _span_stack()
+        self._parent = stack[-1] if stack else ""
+        stack.append(self.name)
+        self._ann = TraceAnnotation(SPAN_PREFIX + self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        _span_stack().pop()
+        tr = self.tracer
+        if tr is not None:
+            meta = dict(self.fields)
+            step = meta.pop("step", -1)
+            meta["name"], meta["parent"] = self.name, self._parent
+            tr._ingest(Event(kind="span", t=t1, wall_s=t1 - self._t0,
+                             step=step, partition=tr.partition, meta=meta),
+                       ring=False)
+        return False
+
+
+def span(tracer: Optional[Tracer], name: str, **fields) -> Span:
+    """Context manager timing one region of host work; see :class:`Span`.
+    With ``tracer=None`` the region is still annotated on the profiler
+    trace, but no event is recorded."""
+    return Span(tracer, name, fields)
 
 
 # ---------------------------------------------------------------------------
