@@ -302,10 +302,11 @@ def prefill(params: Params, inputs: jax.Array, cfg: ArchConfig,
 
 def _decode_block(kind: str, x, p: Params, cache: Params, pos,
                   cfg: ArchConfig, rt: RuntimeCfg, shared: Optional[Params],
-                  page_map=None):
+                  page_map=None, layer=None):
     """Returns (x, new_cache). With ``page_map`` (B, max_pages), the
-    PAGED_KINDS blocks read/write the pooled paged cache instead of the
-    dense per-slot one."""
+    PAGED_KINDS blocks read the stacked pooled paged cache at super-layer
+    ``layer`` instead of the dense per-slot one, and return the step's
+    new rows (:func:`_paged_decode_attn`)."""
     if kind == "shared_attn":
         p = shared
     window = cfg.window_size if kind == "attn_local" else 0
@@ -314,7 +315,7 @@ def _decode_block(kind: str, x, p: Params, cache: Params, pos,
                 "shared_attn"):
         h = rms_norm(x, p["norm1"], cfg.norm_eps)
         if page_map is not None and kind in PAGED_KINDS:
-            a, new_kv = _paged_decode_attn(h, p["attn"], cache, pos,
+            a, new_kv = _paged_decode_attn(h, p["attn"], cache, layer, pos,
                                            page_map, cfg, rt)
         else:
             a, new_kv = _decode_attn(h, p["attn"], cache, pos, cfg, rt,
@@ -398,26 +399,56 @@ def _decode_attn(x, p, cache, pos, cfg: ArchConfig, rt: RuntimeCfg,
     return out, {"k": kc, "v": vc, "pos": posc}
 
 
-def _paged_decode_attn(x, p, cache, pos, page_map, cfg: ArchConfig,
+def _page_slot(posb, page_map, trash: int, page_size: int):
+    """(physical page, in-page offset) of row ``posb`` of each slot.
+
+    Idle slots (current page entry ``-1``) are routed to the *trash* page
+    (the pool's last page, owned by no slot) so live pages are never
+    aliased. Positions at/past the table's capacity (``max_pages *
+    page_size == max_len``) go there too rather than alias the clipped
+    last page — the dense path drops such out-of-bounds rows. Plain
+    decode never reaches them (the host finishes a slot at max_len), but
+    a k>1 speculative verify probes a few positions past the end of an
+    almost-full slot."""
+    mp = page_map.shape[1]
+    lpage = jnp.clip(posb // page_size, 0, mp - 1)
+    off = posb % page_size
+    phys = jnp.take_along_axis(page_map, lpage[:, None], axis=1)[:, 0]
+    phys = jnp.where((phys >= 0) & (posb < mp * page_size), phys, trash)
+    return phys, off
+
+
+def _paged_decode_attn(x, p, cache, layer, pos, page_map, cfg: ArchConfig,
                        rt: RuntimeCfg):
     """Decode attention over the pooled paged cache.
 
-    ``cache`` leaves are pools: k/v ``(n_pages+1, page_size, kvh, hd)``,
-    pos ``(n_pages+1, page_size)``; ``page_map`` is ``(B, max_pages)``
-    int32 (``-1`` = unallocated). The last physical page is a *trash*
-    page owned by no slot: writes for slots whose current page entry is
-    ``-1`` (idle slots) land there, and gathers of unallocated logical
-    pages read from it — its rows are never attended to because an
-    unallocated logical page's row indices all exceed the slot's ``pos``
-    (tables are prefixes, core/paging.py) and the causal ``arange <=
-    pos`` mask kills them.
+    ``cache`` holds every super-layer's k/v pools, of which this reads
+    ``layer`` — ``(n_super, n_pages+1, page_size, kvh*hd)`` — and this
+    layer's pos pool ``(n_pages+1, page_size)``; ``page_map`` is
+    ``(B, max_pages)`` int32 (``-1`` = unallocated). The last physical
+    page is a *trash* page owned by no slot: gathers of unallocated
+    logical pages read from it — its rows are never attended to because
+    an unallocated logical page's row indices all exceed the slot's
+    ``pos`` (tables are prefixes, core/paging.py) and the causal
+    ``arange <= pos`` mask kills them.
+
+    The pools are read-only here. k/v are gathered from the stack
+    directly: a per-layer slice of a pool would be materialized first
+    (134 MB a layer on the DeepSeek stage). The pos pool is stored
+    pages-minor, and handling the whole stack relayouts it, so the scan
+    slices it. The current token's k/v/pos go into the *gathered*
+    per-slot copy at row ``pos`` (dropped past ``max_len``, as the dense
+    scatter drops them), and the new k/v rows ``(B, kvh*hd)`` and
+    positions ``(B,)`` are returned for :func:`paged_decode_step` to
+    write into every layer's pool in one scatter after the layer scan.
 
     Exactness contract: the gather reconstructs each slot's KV in the
     *identical* ``(B, max_len, ...)`` layout the dense path uses (row i
-    holds position i; ``max_pages * page_size == max_len``), then runs
-    the *same* mask/softmax/einsum code — masked rows are the same
-    NEG_INF constant in both, their softmax weight underflows to exactly
-    0.0, and 0 × finite garbage is 0, so paged greedy decode is
+    holds position i; ``max_pages * page_size == max_len``), with the
+    current row written as the dense path writes it, then runs the
+    *same* mask/softmax/einsum code — masked rows are the same NEG_INF
+    constant in both, their softmax weight underflows to exactly 0.0,
+    and 0 × finite garbage is 0, so paged greedy decode is
     token-for-token identical to dense.
     """
     from repro.models.layers import batched_einsum, shard_tag
@@ -433,34 +464,23 @@ def _paged_decode_attn(x, p, cache, pos, page_map, cfg: ArchConfig,
     q = shard_tag(rt, q, "decode_q")
 
     kp, vc_pool, pp = cache["k"], cache["v"], cache["pos"]
-    ps = kp.shape[1]
+    ps = kp.shape[2]
     mp = page_map.shape[1]
-    trash = kp.shape[0] - 1
+    smax = mp * ps
+    trash = kp.shape[1] - 1
     page_map = jnp.asarray(page_map, jnp.int32)
 
-    # write the current token at (physical page, in-page offset); idle
-    # slots (entry -1) are routed to the trash page so live pages are
-    # never aliased.
-    lpage = jnp.clip(posb // ps, 0, mp - 1)
-    off = posb % ps
-    phys = jnp.take_along_axis(page_map, lpage[:, None], axis=1)[:, 0]
-    # positions at/past the table's capacity (mp * ps == max_len) must not
-    # alias the clipped last page — the dense path's scatter drops such
-    # out-of-bounds rows, so the paged path routes them to trash. Plain
-    # decode never reaches here (the host finishes a slot at max_len), but
-    # a k>1 speculative verify legitimately probes a few positions past
-    # the end of an almost-full slot.
-    phys = jnp.where((phys >= 0) & (posb < mp * ps), phys, trash)
-    kp = kp.at[phys, off].set(k[:, 0].astype(kp.dtype))
-    vc_pool = vc_pool.at[phys, off].set(v[:, 0].astype(vc_pool.dtype))
-    pp = pp.at[phys, off].set(posb)
-
-    # gather back into the dense (b, max_len, ...) layout
+    # gather into the dense (b, max_len, ...) layout, then write the
+    # current token at row pos of the gathered copy
     safe = jnp.where(page_map >= 0, page_map, trash)       # (b, mp)
-    kc = kp[safe].reshape(b, mp * ps, kvh, hd)
-    vc = vc_pool[safe].reshape(b, mp * ps, kvh, hd)
-    posc = pp[safe].reshape(b, mp * ps)
-    smax = mp * ps
+    k_row = k[:, 0].reshape(b, kvh * hd).astype(kp.dtype)
+    v_row = v[:, 0].reshape(b, kvh * hd).astype(vc_pool.dtype)
+    bidx = jnp.arange(b)
+    kc = kp[layer, safe].reshape(b, smax, kvh * hd) \
+        .at[bidx, posb].set(k_row, mode="drop").reshape(b, smax, kvh, hd)
+    vc = vc_pool[layer, safe].reshape(b, smax, kvh * hd) \
+        .at[bidx, posb].set(v_row, mode="drop").reshape(b, smax, kvh, hd)
+    posc = pp[safe].reshape(b, smax).at[bidx, posb].set(posb, mode="drop")
 
     # from here: byte-identical to the dense _decode_attn arithmetic
     scale = hd ** -0.5
@@ -475,7 +495,7 @@ def _paged_decode_attn(x, p, cache, pos, page_map, cfg: ArchConfig,
                        out_dtype=jnp.float32)
     o = o.reshape(b, 1, h * hd).astype(x.dtype)
     out = dense(o, p["w_o"], cfg, rt, "o")
-    return out, {"k": kp, "v": vc_pool, "pos": pp}
+    return out, {"k": k_row, "v": v_row, "pos": posb}
 
 
 def decode_step(params: Params, tokens: jax.Array, caches: Params, pos,
@@ -528,28 +548,55 @@ def paged_decode_step(params: Params, tokens: jax.Array, caches: Params,
 
     ``page_map`` (B, max_pages) int32 is shared by every layer — one
     physical page id names the same rows in each layer's pool — so it is
-    closed over by the scan body rather than scanned. Tail blocks and
-    non-PAGED_KINDS leaves behave exactly as in ``decode_step``."""
+    closed over by the scan body rather than scanned, and so are the
+    PAGED_KINDS k/v pools: the scan only reads them, at its layer index
+    (pos pools are scanned), and emits each layer's new k/v rows and
+    positions; one scatter after the scan writes them into the stacked
+    pools at ``(layer, page, offset)`` — in place when the caller
+    donates ``caches``. Tail blocks and non-PAGED_KINDS leaves behave
+    exactly as in ``decode_step``."""
     x = embed_tokens(tokens, params["embed"]).astype(rt.act_dtype)
     shared = params.get("shared_attn")
     pat = cfg.superlayer_pattern
 
     from repro.models.layers import shard_tag
 
+    paged = [f"b{i}" for i, kind in enumerate(pat) if kind in PAGED_KINDS]
+    pools = caches["layers"]
+    scanned = {n: {"pos": c["pos"]} if n in paged else c
+               for n, c in pools.items()}
+
     def scan_body(carry, inp):
         x = carry
-        p_super, cache_super = inp
+        p_super, cache_super, layer = inp
         x = shard_tag(rt, x, "act_btd")
         new_caches = {}
         for i, kind in enumerate(pat):
-            x, nc = _decode_block(kind, x, p_super[f"b{i}"],
-                                  cache_super[f"b{i}"], pos, cfg, rt,
-                                  shared, page_map=page_map)
-            new_caches[f"b{i}"] = nc
+            name = f"b{i}"
+            cache = cache_super[name]
+            if name in paged:
+                cache = dict(cache, k=pools[name]["k"], v=pools[name]["v"])
+            x, new_caches[name] = _decode_block(
+                kind, x, p_super[name], cache, pos, cfg, rt, shared,
+                page_map=page_map, layer=layer)
         return x, new_caches
 
     x, new_layer_caches = jax.lax.scan(
-        scan_body, x, (params["layers"], caches["layers"]))
+        scan_body, x, (params["layers"], scanned,
+                       jnp.arange(cfg.num_superlayers)))
+
+    b = tokens.shape[0]
+    posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    page_map = jnp.asarray(page_map, jnp.int32)
+    layer = jnp.arange(cfg.num_superlayers)[:, None]
+    for name in paged:
+        pool, rows = pools[name], new_layer_caches[name]
+        phys, off = _page_slot(posb, page_map, pool["k"].shape[1] - 1,
+                               pool["k"].shape[2])
+        # index the layer too, so each update is one contiguous row
+        at = (layer, phys[None], off[None])
+        new_layer_caches[name] = {
+            key: pool[key].at[at].set(rows[key]) for key in pool}
 
     new_caches = {"layers": new_layer_caches}
     if "tail" in params:
@@ -621,15 +668,9 @@ def _rollback_caches(snaps, n_acc, posb, cfg: ArchConfig, page_map=None):
             # the trash page (duplicate trash writes are fine — the
             # scrubbed value is a constant).
             ps = f.shape[2]
-            mp = page_map.shape[1]
             trash = f.shape[1] - 1
             for j in range(1, k):
-                pj = posb + j
-                lpage = jnp.clip(pj // ps, 0, mp - 1)
-                off = pj % ps
-                phys = jnp.take_along_axis(page_map, lpage[:, None],
-                                           axis=1)[:, 0]
-                phys = jnp.where((phys >= 0) & (pj < mp * ps), phys, trash)
+                phys, off = _page_slot(posb + j, page_map, trash, ps)
                 phys = jnp.where(j > n_acc, phys, trash)
                 f = f.at[:, phys, off].set(zero)
             return f
@@ -770,7 +811,12 @@ def init_paged_cache(cfg: ArchConfig, batch: int, max_len: int,
     ``_paged_decode_attn``) of ``page_size`` rows each, shared by all
     slots; everything else (window caches, SSM state, tail) stays
     slot-indexed dense. Requires ``max_len % page_size == 0`` so the
-    gathered layout matches the dense one row-for-row."""
+    gathered layout matches the dense one row-for-row.
+
+    A k/v row is stored as one ``kvh * hd`` vector: with ``(kvh, hd)``
+    minor dims (8, 64 on granite) the TPU lays a pool out pages-minor to
+    avoid lane padding, and every page gather or row scatter would then
+    relayout the whole pool."""
     if max_len % page_size:
         raise ValueError(f"max_len={max_len} not a multiple of "
                          f"page_size={page_size}")
@@ -781,8 +827,8 @@ def init_paged_cache(cfg: ArchConfig, batch: int, max_len: int,
     def one_block(kind):
         if kind in PAGED_KINDS:
             p1 = pages + 1
-            return {"k": jnp.zeros((p1, page_size, kvh, hd), dtype),
-                    "v": jnp.zeros((p1, page_size, kvh, hd), dtype),
+            return {"k": jnp.zeros((p1, page_size, kvh * hd), dtype),
+                    "v": jnp.zeros((p1, page_size, kvh * hd), dtype),
                     "pos": jnp.full((p1, page_size), -1, jnp.int32)}
         return _block_cache(kind, batch, max_len, cfg, dtype)
 

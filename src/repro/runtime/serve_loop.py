@@ -160,15 +160,17 @@ def clear_jit_cache() -> None:
     _JIT_CACHE.clear()
 
 
-def _cached_jit(kind: str, maker: Callable[[], Callable], *key_parts):
+def _cached_jit(kind: str, maker: Callable[[], Callable], *key_parts,
+                donate_argnums: Tuple[int, ...] = ()):
     try:
         key = (kind,) + key_parts
         hash(key)
     except TypeError:                 # unhashable cfg/rt (e.g. shard_fn)
-        return jax.jit(maker())
+        return jax.jit(maker(), donate_argnums=donate_argnums)
     fn = _JIT_CACHE.get(key)
     if fn is None:
-        fn = _JIT_CACHE[key] = jax.jit(maker())
+        fn = _JIT_CACHE[key] = jax.jit(maker(),
+                                       donate_argnums=donate_argnums)
     _JIT_CACHE.move_to_end(key)
     while len(_JIT_CACHE) > JIT_CACHE_MAX:
         _JIT_CACHE.popitem(last=False)
@@ -229,11 +231,12 @@ def _clear_slot_cache(caches, slot):
 
 # -- paged-cache twins of the slot helpers ----------------------------------
 # Paged leaves live under caches["layers"]["b{i}"] for PAGED_KINDS blocks,
-# pooled as (n_super, n_pages+1, page_size, ...); everything else (window
-# caches, SSM state, tail) keeps the dense slot-indexed layout and is
-# handled exactly like the dense helpers above. ``phys`` vectors are padded
-# to the per-slot table width with the trash-page index so the jitted
-# scatters have a fixed shape — trash writes only ever carry scrub values.
+# pooled as (n_super, n_pages+1, page_size, ...), a k/v row flattened to
+# kvh*hd (init_paged_cache); everything else (window caches, SSM state,
+# tail) keeps the dense slot-indexed layout and is handled exactly like the
+# dense helpers above. ``phys`` vectors are padded to the per-slot table
+# width with the trash-page index so the jitted scatters have a fixed
+# shape — trash writes only ever carry scrub values.
 
 def _paged_blocks(pat) -> frozenset:
     return frozenset(f"b{i}" for i, kind in enumerate(pat)
@@ -265,6 +268,7 @@ def _paged_write_prompt(pat, full, new, slot, phys):
             ps = f.shape[2]
             mp = phys.shape[0]
             s = row.shape[1]
+            row = row.reshape(row.shape[:2] + f.shape[3:])  # (kvh*hd) rows
             pad_shape = (row.shape[0], mp * ps - s) + row.shape[2:]
             if _leaf_key(path) == "pos":
                 fill = jnp.full(pad_shape, -1, row.dtype)
@@ -444,10 +448,15 @@ class ServeSession:
                 self.caches = self._put(init_paged_cache(
                     cfg, batch_slots, max_len, self.page_size, self.pages))
             self._page_map = self._put(self.pager.page_map())
+            # the caches are donated: the step writes the new KV rows
+            # into the pools in place, and dispatch_decode drops its
+            # reference to the old tree as it dispatches. Speculative
+            # steps read self.caches twice (draft, verify): not donated.
             self.step_fn = _cached_jit(
                 "serve_paged",
                 lambda: make_paged_serve_step(cfg, rt, temperature),
-                cfg, rt, temperature, ambient, self.page_size, self.pages)
+                cfg, rt, temperature, ambient, self.page_size, self.pages,
+                donate_argnums=(2,))
         else:
             self.page_size, self.pages = 0, 0
             self.pager = None
@@ -953,7 +962,8 @@ class ServeSession:
                     n=self.cfg.d_ff, precision=self.cfg.precision,
                     **self._policy_tag(), wall_s=ready - ticket.t0,
                     lane=ticket.lane, overlap_group=ticket.overlap_group,
-                    meta={"n_active": self.n_active})
+                    meta={"n_active": self.n_active,
+                          "kv_inplace": int(self.paged)})
             self.tokens = nxt
             done = list(ticket.oom_done)
             for i, req in enumerate(self.slots):
@@ -996,7 +1006,8 @@ class ServeSession:
                 n=self.cfg.d_ff, precision=self.cfg.precision,
                 **self._policy_tag(), wall_s=ready - ticket.t0,
                 lane=ticket.lane, overlap_group=ticket.overlap_group,
-                meta={"n_active": self.n_active, "spec_k": k})
+                meta={"n_active": self.n_active, "spec_k": k,
+                      "kv_inplace": 0})
         self.tokens = nxt
         done = list(ticket.oom_done)
         trimmed = False

@@ -267,6 +267,194 @@ def test_jit_cache_key_includes_page_geometry(model):
 
 
 # ---------------------------------------------------------------------------
+# In-place pool update: read-only pools in the layer scan, one scatter after
+# ---------------------------------------------------------------------------
+
+def _scatter_then_gather_attn(x, p, cache, layer, pos, page_map, cfg, rt):
+    """The paged attention the in-place path replaced, kept as the
+    reference: write the token into the layer's own pool (``cache``,
+    ``layer`` unused), then gather the same pool back out, and return
+    the whole pool."""
+    import jax.numpy as jnp
+    from repro.models import attention as attn_mod
+    from repro.models import transformer as tf
+    from repro.models.layers import batched_einsum
+    b = x.shape[0]
+    h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    q = tf.dense(x, p["w_q"], cfg, rt, "q").reshape(b, 1, h, hd)
+    k = tf.dense(x, p["w_k"], cfg, rt, "k").reshape(b, 1, kvh, hd)
+    v = tf.dense(x, p["w_v"], cfg, rt, "v").reshape(b, 1, kvh, hd)
+    q = attn_mod.apply_rope(q, posb[:, None], cfg.rope_theta)
+    k = attn_mod.apply_rope(k, posb[:, None], cfg.rope_theta)
+    kp, vp, pp = cache["k"], cache["v"], cache["pos"]
+    ps, mp, trash = kp.shape[1], page_map.shape[1], kp.shape[0] - 1
+    smax = mp * ps
+    lpage = jnp.clip(posb // ps, 0, mp - 1)
+    phys = jnp.take_along_axis(page_map, lpage[:, None], axis=1)[:, 0]
+    phys = jnp.where((phys >= 0) & (posb < smax), phys, trash)
+    off = posb % ps
+    kp = kp.at[phys, off].set(k[:, 0].reshape(b, -1).astype(kp.dtype))
+    vp = vp.at[phys, off].set(v[:, 0].reshape(b, -1).astype(vp.dtype))
+    pp = pp.at[phys, off].set(posb)
+    safe = jnp.where(page_map >= 0, page_map, trash)
+    kc = kp[safe].reshape(b, smax, kvh, hd)
+    vc = vp[safe].reshape(b, smax, kvh, hd)
+    posc = pp[safe].reshape(b, smax)
+    s = batched_einsum("bkgd,bskd->bkgs", q.reshape(b, kvh, h // kvh, hd),
+                       kc, rt, out_dtype=jnp.float32) * hd ** -0.5
+    valid = (posc >= 0) & (posc <= posb[:, None])
+    valid &= jnp.arange(smax)[None, :] <= posb[:, None]
+    s = jnp.where(valid[:, None, None, :], s, attn_mod.NEG_INF)
+    pr = jax.nn.softmax(s, axis=-1)
+    o = batched_einsum("bkgs,bskd->bkgd", pr.astype(vc.dtype), vc, rt,
+                       out_dtype=jnp.float32)
+    o = o.reshape(b, 1, h * hd).astype(x.dtype)
+    return tf.dense(o, p["w_o"], cfg, rt, "o"), {"k": kp, "v": vp, "pos": pp}
+
+
+def _scatter_then_gather_step(params, tokens, caches, pos, page_map, cfg,
+                              rt):
+    """Reference paged decode step: the pools ride through the layer scan
+    as xs/ys, each layer writing its pool before gathering it."""
+    from repro.models import transformer as tf
+
+    def body(x, inp):
+        p_super, c_super = inp
+        out = {}
+        for i, kind in enumerate(cfg.superlayer_pattern):
+            x, out[f"b{i}"] = tf._decode_block(
+                kind, x, p_super[f"b{i}"], c_super[f"b{i}"], pos, cfg, rt,
+                params.get("shared_attn"), page_map=page_map)
+        return x, out
+
+    x = tf.embed_tokens(tokens, params["embed"]).astype(rt.act_dtype)
+    x, layers = jax.lax.scan(body, x, (params["layers"], caches["layers"]))
+    x = tf.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return tf.lm_logits(x[:, 0], params["head"], cfg.vocab_size), \
+        {"layers": layers}
+
+
+def _filled_pools(cfg, page_map, pos, seed=0):
+    """A paged cache whose allocated pages hold random k/v, rows below
+    each slot's position marked written (pos = row), the rest unwritten."""
+    import jax.numpy as jnp
+    from repro.models import init_paged_cache
+    b, mp = page_map.shape
+    caches = init_paged_cache(cfg, b, MAX_LEN, PAGE, b * mp)
+    rng = np.random.default_rng(seed)
+    for leaves in caches["layers"].values():
+        if "pos" not in leaves or leaves["pos"].shape[1] != b * mp + 1:
+            continue
+        for key in ("k", "v"):
+            leaves[key] = jnp.asarray(
+                rng.standard_normal(leaves[key].shape), leaves[key].dtype)
+        posp = np.asarray(leaves["pos"]).copy()
+        for slot in range(b):
+            for lp, page in enumerate(page_map[slot]):
+                if page < 0:
+                    continue
+                rows = lp * PAGE + np.arange(PAGE)
+                posp[:, page] = np.where(rows < pos[slot], rows, -1)
+        leaves["pos"] = jnp.asarray(posp)
+    return caches
+
+
+def test_inplace_pool_update_matches_scatter_then_gather(model):
+    """The read-only-pool step against the scatter-into-pool-then-gather
+    step it replaced, for several steps: live slots' logits and every
+    pool row outside the trash page are bit-identical. Slot 1 is idle
+    (every entry -1); slot 2 runs from max_len-2 past the end, where both
+    route the write to the trash page (or drop it)."""
+    import jax.numpy as jnp
+    from repro.models import transformer as tf
+    cfg, params = model
+    pm = np.full((4, MP), -1, np.int32)
+    pm[0, :3] = [3, 17, 1]                  # rows 0..23
+    pm[2] = np.arange(8, 8 + MP)            # every page: rows 0..63
+    pm[3, :2] = [30, 4]                     # rows 0..15
+    pos = np.array([20, 0, MAX_LEN - 2, 9], np.int32)
+    live = [0, 2, 3]
+    caches = _filled_pools(cfg, pm, pos)
+    page_map = jnp.asarray(pm)
+    new_step = jax.jit(lambda c, t, p: tf.paged_decode_step(
+        params, t, c, p, page_map, cfg, RT))
+    ref_step = jax.jit(lambda c, t, p: _scatter_then_gather_step(
+        params, t, c, p, page_map, cfg, RT))
+    new_c, ref_c = caches, caches
+    tok = jnp.asarray(np.arange(4, dtype=np.int32)[:, None] + 11)
+    for step in range(4):
+        p = jnp.asarray(pos + step)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(tf, "_paged_decode_attn", _scatter_then_gather_attn)
+            ref_logits, ref_c = ref_step(ref_c, tok, p)
+        logits, new_c = new_step(new_c, tok, p)
+        np.testing.assert_array_equal(np.asarray(logits)[live],
+                                      np.asarray(ref_logits)[live])
+        for a, r in zip(jax.tree_util.tree_leaves(new_c),
+                        jax.tree_util.tree_leaves(ref_c)):
+            np.testing.assert_array_equal(np.asarray(a)[:, :-1],
+                                          np.asarray(r)[:, :-1])
+        tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
+    # the step past the end wrote nothing outside the trash page
+    assert (np.asarray(new_c["layers"]["b0"]["pos"])[:, pm[2]] < MAX_LEN
+            ).all()
+
+
+def test_paged_serve_step_updates_pools_in_place(model):
+    """The session's compiled paged step aliases every cache input to its
+    output and copies no pool, stacked or per layer."""
+    import re
+    sess = _session(model, slots=4)
+    compiled = sess.step_fn.lower(
+        sess.params, sess.tokens, sess.caches, sess._put(sess.slot_pos),
+        sess._page_map, sess.rng).compile()
+    text = compiled.as_text()
+    n_cache = len(jax.tree_util.tree_leaves(sess.caches))
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    assert alias and alias.group(1).count("may-alias") == n_cache
+    pages = str(sess.pages + 1)
+    pool_copies = [line for line in text.splitlines()
+                   if re.search(r"\[([\d,]*)\]\S* copy\(", line)
+                   and pages in re.search(r"\[([\d,]*)\]", line)
+                   .group(1).split(",")]
+    assert pool_copies == []
+
+
+def test_paged_decode_donates_cache_and_counts_kv_inplace(model):
+    """A plain paged decode step consumes the previous cache buffers
+    (donated: they report deleted) and its
+    ``decode`` event says ``kv_inplace=1``; a speculative step, which
+    reads the cache twice, says 0."""
+    from repro.runtime.telemetry import Tracer
+    cfg, _ = model
+    events = []
+
+    class Sink:
+        def on_event(self, ev):
+            if ev.kind == "decode":
+                events.append(ev)
+
+    tracer = Tracer().add_sink(Sink())
+    sess = _session(model, slots=2, telemetry=tracer)
+    for i, p in enumerate(_prompts(cfg, 2, seed=6)):
+        sess.admit(Request(uid=i, prompt=p, max_new=8))
+    before = jax.tree_util.tree_leaves(sess.caches)
+    sess.join_decode(sess.dispatch_decode())
+    assert all(a.is_deleted() for a in before)
+    assert [ev.meta["kv_inplace"] for ev in events] == [1]
+
+    spec = _session(model, slots=2, speculative=2, telemetry=tracer)
+    spec.admit(Request(uid=9, prompt=_prompts(cfg, 1, seed=7)[0],
+                       max_new=8))
+    kept = jax.tree_util.tree_leaves(spec.caches)
+    spec.join_decode(spec.dispatch_decode())
+    assert events[-1].meta["spec_k"] == 2
+    assert events[-1].meta["kv_inplace"] == 0
+    assert not any(a.is_deleted() for a in kept)
+
+
+# ---------------------------------------------------------------------------
 # Paged flash-decode kernel vs jnp reference (interpret mode)
 # ---------------------------------------------------------------------------
 

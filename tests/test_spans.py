@@ -182,7 +182,7 @@ def test_decode_event_meta_has_no_dispatch_to_ready(model):
     rt, _ = _runtime(model)
     rt.step()
     (dec,) = rt.tracers[0].events("decode")
-    assert dec.meta == {"n_active": 1}
+    assert dec.meta == {"n_active": 1, "kv_inplace": 1}
 
 
 def test_speculative_round_waits_once(model):

@@ -1,4 +1,5 @@
-"""v5e compiles of the main-path Pallas kernels at granite-moe-3b-a800m widths.
+"""v5e compiles of the main-path Pallas kernels at granite-moe-3b-a800m widths,
+and of its whole paged decode step.
 
 Each test compiles for a described (not attached) TPU v5e, so it runs on
 the CPU: what Mosaic refuses here, the chip would refuse too. Nothing is
@@ -6,6 +7,8 @@ executed and no time is measured. The topology is described inside a
 module fixture, never at import, so every xdist worker collects the same
 tests and only the worker given this file loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -14,6 +17,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_arch
 from repro.kernels import flash_attention as fa
 from repro.kernels import registry
+from repro.models import init_paged_cache, init_params
+from repro.models.layers import RuntimeCfg
+from repro.runtime.serve_loop import make_paged_serve_step
 
 CFG = get_arch("granite-moe-3b-a800m")
 D, FF = CFG.d_model, CFG.d_ff
@@ -90,3 +96,43 @@ def test_flash_attention_compiles_for_v5e(one_chip, causal):
         one_chip, ((1, h, s, hd), jnp.bfloat16),
         ((1, kvh, s, hd), jnp.bfloat16), ((1, kvh, s, hd), jnp.bfloat16))
     assert "tpu_custom_call" in text
+
+
+def test_paged_decode_step_copies_no_pool_on_v5e(one_chip):
+    """The whole 32-layer paged decode step at the chat cell's geometry
+    (24 slots of 1536 positions, pages of 16), cache donated as the
+    session donates it: every cache leaf aliases its output, and the
+    only ops that produce a k/v pool, stacked or one layer's, are the
+    in-place scatters of the step's new rows: no copy, relayout or
+    per-layer slice of a pool. (The int32 pos pool, 4.7 MB, is staged
+    for its gather; it is 1/500 of the k/v bytes.)"""
+    slots, max_len, page = 24, 1536, 16
+    pages = slots * max_len // page
+    rt = RuntimeCfg()
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), CFG)))
+    caches = shapes(jax.eval_shape(
+        lambda: init_paged_cache(CFG, slots, max_len, page, pages)))
+    small = shapes({"tokens": jnp.zeros((slots, 1), jnp.int32),
+                    "pos": jnp.zeros((slots,), jnp.int32),
+                    "page_map": jnp.zeros((slots, max_len // page),
+                                          jnp.int32),
+                    "rng": jax.eval_shape(lambda: jax.random.PRNGKey(0))})
+    step = jax.jit(make_paged_serve_step(CFG, rt), donate_argnums=(2,))
+    text = step.lower(params, small["tokens"], caches, small["pos"],
+                      small["page_map"], small["rng"]).compile().as_text()
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    assert alias and alias.group(1).count("may-alias") == \
+        len(jax.tree_util.tree_leaves(caches))
+    pool = rf"bf16\[(?:\d+,)?{pages + 1},{page},{CFG.kv_dim}\]"
+    ops = re.findall(rf"^\s*(?:ROOT )?%\S+ = {pool}\{{[^}}]*\}} (\S+?)\((.*)$",
+                     text, re.M)
+    made = [op for op, rest in ops
+            if op not in ("parameter", "get-tuple-element", "scatter")
+            and not (op == "fusion" and '/scatter"' in rest)]
+    assert ops and made == []
