@@ -65,7 +65,8 @@ def readings(cell: dict, seeds, seconds: float, require_chip: bool = True,
     compiles = R.CompileCounter()
     rows = []
     for seed in seeds:
-        m = R.measure(conf, traffic, seed, seconds, False, devs[0], compiles)
+        m = R.measure(conf, traffic, seed, seconds, False,
+                      R.cell_devices(cell, devs), compiles)
         finished = [r for r in m.recs if r.req.done]
         wrong = sum(len(r.req.out) != r.req.max_new for r in finished)
         sample = R.pick_sample(finished, seed)

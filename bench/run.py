@@ -10,12 +10,15 @@ names a configuration (``bench/configs/<name>.json``) and a traffic mix
 1. checks that JAX sees a TPU with as many chips as the cell asks for, and
    looks its ``device_kind`` up in ``bench/peaks.json``;
 2. makes the weights from ``--seed`` on the device and builds the
-   program's ``ServingRuntime`` with a paged KV cache;
-3. warms up one prefill per prompt length of the mix and the decode step;
+   program's ``ServingRuntime`` with a paged KV cache: the partitions, the
+   placement and the migration that the configuration's ``serving`` block
+   gives (one partition by default), and the traffic's tenants;
+3. warms up, on every partition, one prefill per prompt length of the mix
+   and the decode step;
 4. offers the mix open-loop for its pre-roll, then measures for
    ``--seconds``: one loop submits every request that is due, calls
    ``runtime.step()`` and stamps each new output token on the host clock;
-5. reads the device's peak memory, frees the runtime and its weights,
+5. reads the fullest device's peak memory, frees the runtime and its weights,
    makes the published weights again from the seed, and checks a sample
    of the finished requests against the plain float32 reference
    (``bench/references/<reference>.py``): the gaps by which served
@@ -43,7 +46,7 @@ import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
-from collections import deque  # noqa: E402
+from collections import Counter, deque  # noqa: E402
 from pathlib import Path  # noqa: E402
 from types import SimpleNamespace  # noqa: E402
 from typing import List, Optional  # noqa: E402
@@ -56,12 +59,12 @@ for p in (ROOT / "src", ROOT):
 
 import numpy as np  # noqa: E402
 
+from bench import archs  # noqa: E402
 from bench import model as bmodel  # noqa: E402
 from bench import traffic as btraffic  # noqa: E402
 from bench import trace_reduce  # noqa: E402
 from bench.peaks import peaks_for  # noqa: E402
 
-TENANT = "t0"
 TRACE_SECONDS = 4.0       # traced run: profile the window's last seconds
 SAMPLE_TOKENS = 300       # compared tokens the correctness sample reaches
 SAMPLE_MIN = 6            # ... over at least this many requests
@@ -132,6 +135,11 @@ def check_device(chips: int):
     return devs, peaks
 
 
+def cell_devices(cell: dict, devs) -> list:
+    """The devices a cell runs on: the first ``chips`` of ``devs``."""
+    return list(devs[:int(cell.get("chips", 1))])
+
+
 class CompileCounter:
     """Timestamps of JAX compiles and persistent-cache loads."""
 
@@ -147,7 +155,7 @@ class CompileCounter:
 
 
 class EventSink:
-    """Every prefill and decode event of the program's tracer."""
+    """Every prefill and decode event of the program's tracers."""
 
     def __init__(self):
         self.prefill, self.decode = [], []
@@ -214,40 +222,61 @@ class Rec:
     prefill_start: Optional[float] = None
 
 
-def build_runtime(params, cfg, conf: dict, seed: int):
-    """One partition with a paged cache and one tenant that may hold every
-    slot: the scheduler's default quota (4 slots for a latency-sensitive
-    tenant) would leave most of the batch idle, so the tenant's policy
-    carries a stream budget of ``batch_slots``."""
+def build_runtime(params, cfg, conf: dict, seed: int, devs, tenants):
+    """The serving block's partitions (``partitions``: the program's
+    ``PartitionSpec`` fields, the block's ``policy`` where a partition
+    names none; one partition by default), one to a device where ``devs``
+    (the cell's) has one for each, else logical ones on the first; its
+    ``placement`` and ``migration`` where it gives them. Each of
+    ``tenants`` is placed by the runtime and may hold every slot of its
+    partition: the scheduler's default quota (4 slots for a
+    latency-sensitive tenant) would leave most of the batch idle, so a
+    tenant's policy carries a stream budget of the partition's slots. A
+    partition that no tenant lands on gets an idle tenant ``warm<i>`` for
+    the warm-up."""
     from repro.core import execution as ex
     from repro.models.layers import RuntimeCfg
     from repro.runtime.server import (
-        PartitionSpec, ServingRuntime, ServingSpec)
+        ServingRuntime, ServingSpec, make_partitions)
     s = conf["serving"]
-    spec = ServingSpec(
-        partitions=(PartitionSpec(policy=s["policy"]),),
+    spec = ServingSpec.from_dict(dict(
+        {k: s[k] for k in ("placement", "migration") if k in s},
+        partitions=[dict({"policy": s["policy"]}, **p)
+                    for p in s.get("partitions", [{}])],
         batch_slots=s["batch_slots"], max_len=s["max_len"], paged=True,
-        page_size=s["page_size"], seed=bmodel.jax_seed(seed))
+        page_size=s["page_size"], seed=bmodel.jax_seed(seed)))
+    n = spec.n_partitions
     runtime = ServingRuntime(params, cfg, spec, rt=RuntimeCfg(),
-                             tracer_capacity=1 << 20)
-    runtime.add_tenant(TENANT, partition=0, policy=dataclasses.replace(
-        ex.parse_policy(s["policy"]), streams=s["batch_slots"]))
+                             tracer_capacity=1 << 20,
+                             partitions=make_partitions(n, devs[:n]))
+    policy = dataclasses.replace(ex.parse_policy(s["policy"]), streams=max(
+        p.batch_slots or spec.batch_slots for p in spec.partitions))
+    for tenant in tenants:
+        runtime.add_tenant(tenant, policy=policy)
+    for i in set(range(n)) - set(runtime.tenant_partition.values()):
+        runtime.add_tenant(f"warm{i}", partition=i, policy=policy)
     return runtime
 
 
-def warm_up(runtime, traffic: dict, vocab: int, slots: int,
-            seed: int) -> None:
-    """One request per prompt length of the mix, then the shortest length
-    until every slot holds one: compiles each prefill and prompt write,
-    the decode step, and the slot bookkeeping at every slot index."""
+def warm_up(runtime, traffic: dict, vocab: int, seed: int) -> None:
+    """On every partition, through the first tenant placed there: one
+    request per prompt length of the mix, then the shortest length until
+    every slot holds one. Compiles each prefill and prompt write, the
+    decode step, and the slot bookkeeping at every slot index."""
     from repro.runtime.serve_loop import Request
     rng = np.random.default_rng(seed + 1)
-    lens = list(traffic["prompt"]["lengths"])
-    lens += [min(lens)] * max(0, slots - len(lens))
-    for i, lp in enumerate(lens):
-        runtime.submit(TENANT, Request(
-            uid=-1 - i, prompt=rng.integers(0, vocab, lp, dtype=np.int32),
-            max_new=3))
+    first = {}
+    for tenant, i in runtime.tenant_partition.items():
+        first.setdefault(i, tenant)
+    uid = -1
+    for i, sess in enumerate(runtime.sessions):
+        lens = list(traffic["prompt"]["lengths"])
+        lens += [min(lens)] * max(0, sess.batch_slots - len(lens))
+        for lp in lens:
+            runtime.submit(first[i], Request(
+                uid=uid, prompt=rng.integers(0, vocab, lp, dtype=np.int32),
+                max_new=3))
+            uid -= 1
     runtime.drain()
 
 
@@ -285,7 +314,7 @@ def serve(runtime, arrivals, t0: float, w0: float, w1: float,
                     a = pending.popleft()
                     rec = Rec(due=t0 + a.due_s, req=Request(
                         uid=a.uid, prompt=a.prompt, max_new=a.max_new))
-                    runtime.submit(TENANT, rec.req)
+                    runtime.submit(a.tenant, rec.req)
                     rec.submit_t = time.perf_counter()
                     recs.append(rec)
                     live[a.uid] = rec
@@ -458,8 +487,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     dev = devs[0]
     conf = bmodel.load_config(cell["config"], bench)
     traffic = btraffic.load_traffic(cell["traffic"], bench)
+    kinds = archs.of(conf).layer_kinds(conf)
     log(f"device {dev.platform} {dev.device_kind} x{len(devs)}; cell "
-        f"{cell['name']}: {conf['name']}, {btraffic.summary(traffic)}")
+        f"{cell['name']}: {conf['name']} ({archs.name_of(conf)}: "
+        f"{dict(Counter(kinds))}), {btraffic.summary(traffic)}")
     compiles = CompileCounter()
     jax.monitoring.register_event_duration_secs_listener(compiles)
     try:
@@ -470,20 +501,22 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
 
 
 def measure(conf: dict, traffic: dict, seed: int, seconds: float,
-            trace: bool, dev, compiles: CompileCounter) -> SimpleNamespace:
-    """Weights, runtime, warm-up, pre-roll and window: everything up to
-    the window's close. The runtime and its weights are dropped
-    before returning."""
+            trace: bool, devs, compiles: CompileCounter) -> SimpleNamespace:
+    """Weights, runtime, warm-up, pre-roll and window on the cell's
+    devices ``devs``: everything up to the window's close. The runtime
+    and its weights are dropped before returning; ``peak`` is the
+    fullest device's."""
     cfg = bmodel.arch_config(conf)
     t = time.perf_counter()
     params = bmodel.init_weights(conf, seed)
     log(f"weights from seed: {time.perf_counter() - t:.1f} s")
     t = time.perf_counter()
-    runtime = build_runtime(params, cfg, conf, seed)
+    runtime = build_runtime(params, cfg, conf, seed, devs,
+                            btraffic.tenants(traffic))
     sink = EventSink()
-    runtime.tracers[0].add_sink(sink)
-    warm_up(runtime, traffic, conf["vocab_size"],
-            conf["serving"]["batch_slots"], seed)
+    for tracer in runtime.tracers:
+        tracer.add_sink(sink)
+    warm_up(runtime, traffic, conf["vocab_size"], seed)
     log(f"runtime built and warmed up: {time.perf_counter() - t:.1f} s, "
         f"{len(compiles.times)} compiles or cache loads so far")
 
@@ -501,7 +534,8 @@ def measure(conf: dict, traffic: dict, seed: int, seconds: float,
     try:
         recs, steps, trace_iv, queued = serve(runtime, arrivals, t0, w0, w1,
                                               trace_dir)
-        stats = dev.memory_stats() or {}
+        peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                   for d in devs)
         reduced = None
         if trace_dir is not None:
             files = sorted(Path(trace_dir).rglob("*.xplane.pb"))
@@ -521,12 +555,13 @@ def measure(conf: dict, traffic: dict, seed: int, seconds: float,
     return SimpleNamespace(
         recs=recs, steps=steps, trace_iv=trace_iv, reduced=reduced,
         sink=sink, w0=w0, w1=w1, queued=queued, gc_pauses=pauses,
-        peak=int(stats.get("peak_bytes_in_use", 0)))
+        peak=peak)
 
 
 def _run(cell, conf, traffic, seed, seconds, trace, bench, dev, devs, peaks,
          compiles) -> dict:
-    m = measure(conf, traffic, seed, seconds, trace, dev, compiles)
+    m = measure(conf, traffic, seed, seconds, trace,
+                cell_devices(cell, devs), compiles)
     setup_s = m.w0 - T_START
     recs, w0, w1, peak, reduced = m.recs, m.w0, m.w1, m.peak, m.reduced
     sink, steps, trace_iv = m.sink, m.steps, m.trace_iv

@@ -110,8 +110,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
 
     R.EventSink, trace_reduce.load = SpanSink, keep
     try:
-        m = R.measure(conf, traffic, seed, seconds, trace, devs[0],
-                      R.CompileCounter())
+        m = R.measure(conf, traffic, seed, seconds, trace,
+                      R.cell_devices(cell, devs), R.CompileCounter())
     finally:
         R.EventSink, trace_reduce.load = SpanSink.__base__, load
     spans = [sp for sp in m.sink.spans if m.w0 <= sp[0] and sp[1] <= m.w1]

@@ -32,7 +32,8 @@ def sweep(cell: dict, rates, seconds: float, seed: int):
     rows = []
     for rate in rates:
         mix = dict(traffic, rate_per_s=rate)
-        m = R.measure(conf, mix, seed, seconds, False, devs[0], compiles)
+        m = R.measure(conf, mix, seed, seconds, False,
+                      R.cell_devices(cell, devs), compiles)
         e2e = R.end_to_end(m.recs, m.w0, m.w1)
         done = sum(m.w0 <= r.due < m.w1 and r.req.done for r in m.recs)
         occ = [n for s, e, n in m.sink.decode if m.w0 <= s and e <= m.w1]

@@ -17,19 +17,32 @@ E2E = [("ttft_p90_ms", "ms"), ("itl_p95_ms", "ms"),
        ("output_tokens_per_s", "tokens/s"), ("setup_s", "s")]
 
 
-@pytest.fixture
-def tiny_bench(tmp_path):
-    """(bench dir, cell) for a two-layer MoE under a light mix."""
+def make_bench(tmp_path, config: str, traffic: str, archs=(),
+               references=()):
+    """(bench dir, cell) for the configuration and traffic files
+    ``config`` and ``traffic`` of ``data/``, with the architecture and
+    reference modules (data file, name) beside them."""
     root = tmp_path / "bench"
     (root / "configs").mkdir(parents=True)
     (root / "traffic").mkdir()
+    (root / "archs").mkdir()
     shutil.copytree(BENCH / "metrics", root / "metrics")
     shutil.copytree(BENCH / "references", root / "references")
-    shutil.copy(DATA / "tiny.json", root / "configs" / "tiny.json")
-    shutil.copy(DATA / "tiny-traffic.json", root / "traffic" / "tiny.json")
+    for kind, files in (("archs", archs), ("references", references)):
+        for src, name in files:
+            shutil.copy(DATA / src, root / kind / f"{name}.py")
+    name = config.removesuffix(".json")
+    shutil.copy(DATA / config, root / "configs" / f"{name}.json")
+    shutil.copy(DATA / traffic, root / "traffic" / f"{name}.json")
     names = sorted(p.stem for p in (BENCH / "metrics").glob("*.py"))
-    cell = {"name": "tiny.chat", "config": "tiny", "traffic": "tiny",
+    cell = {"name": f"{name}.chat", "config": name, "traffic": name,
             "chips": 1,
             "end_to_end": [{"name": n, "unit": u} for n, u in E2E],
             "per_layer": [{"name": n, "unit": "x"} for n in names]}
     return root, cell
+
+
+@pytest.fixture
+def tiny_bench(tmp_path):
+    """(bench dir, cell) for a two-layer MoE under a light mix."""
+    return make_bench(tmp_path, "tiny.json", "tiny-traffic.json")

@@ -60,6 +60,9 @@ def test_traced_run_gives_per_layer_metrics(tiny_bench):
     assert {"queue_wait_p90_ms", "batch_occupancy", "prefill_ms_per_ktok",
             "decode_step_ms"} <= set(res["metrics"])
     assert "device_idle_share" not in res["metrics"]
+    # a metric split by the cells it moves reads as the one it splits
+    assert res["metrics"]["decode_step_ms.overload"] == \
+        res["metrics"]["decode_step_ms"]
     assert "window_s" in res["device"] and "busy_s" in res["device"]
 
 

@@ -1,7 +1,11 @@
 """The wall-clock traffic generator and finding files by name."""
+import collections
+import hashlib
 import json
+import struct
 
 import numpy as np
+import pytest
 
 from bench import model, traffic
 from bench import run as R
@@ -90,3 +94,58 @@ def test_new_files_are_found_by_name(tmp_path):
     cell = R.load_cell("new-model.new-mix", tmp_path)
     assert [m["name"] for m in R.metrics_of(cell, "per_layer")] == \
         ["new_metric"]
+
+
+def _digest(sched) -> str:
+    h = hashlib.sha256()
+    for a in sched:
+        h.update(struct.pack("<qdq", a.uid, a.due_s, a.max_new))
+        h.update(a.prompt.tobytes())
+    return h.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name,vocab,want", [
+    ("granite-moe.chat", 49155, "c0dc76befc78f4cb"),
+    ("deepseek-llm-67b.code", 102400, "e58b39a80affbac6"),
+    ("granite-moe.chat-overload", 49155, "30a48aa6b535463a"),
+])
+def test_file_without_tenants_gives_the_parents_schedule(name, vocab, want):
+    """The schedule that the generator of commit 3904110, before tenants,
+    makes of each file, bit for bit."""
+    sched = traffic.schedule(traffic.load_traffic(name), 2**31 + 977, 45,
+                             vocab)
+    assert _digest(sched) == want
+    assert {a.tenant for a in sched} == {traffic.DEFAULT_TENANT}
+
+
+@pytest.mark.parametrize("tenants,shares", [
+    ({"n": 5, "zipf_s": 1.1}, 1.0 / np.arange(1, 6) ** 1.1),
+    ({"ids": ["chat", "batch", "eval"], "shares": [0.6, 0.3, 0.1]},
+     np.array([0.6, 0.3, 0.1])),
+], ids=["zipf", "ids"])
+def test_tenant_shares_follow_the_file(tenants, shares):
+    mix = dict(MIX, tenants=tenants)
+    plain = traffic.schedule(MIX, 2**31 + 977, 45, 49155)
+    for seed in (2**31 + 977, 4):
+        sched = traffic.schedule(mix, seed, 45, 49155)
+        want = dict(zip(traffic.tenants(mix), shares / shares.sum()))
+        got = collections.Counter(a.tenant for a in sched)
+        assert set(got) == set(want)
+        for t, share in want.items():
+            # exact proportions: off by less than one request
+            assert abs(got[t] - share * len(sched)) < 1.0
+            # and within sampling error over any stretch of 60 requests
+            part = sum(a.tenant == t for a in sched[:60])
+            assert abs(part - 60 * share) <= 3 * (60 * share) ** 0.5 + 1
+    # the tenants' draws leave the rest of the schedule as it was
+    sched = traffic.schedule(mix, 2**31 + 977, 45, 49155)
+    assert _digest(sched) == _digest(plain)
+
+
+def test_malformed_tenants_are_refused():
+    for bad in ({"ids": ["a", "a"], "shares": [1, 1]},
+                {"ids": ["a"], "shares": [0]},
+                {"ids": ["a", "b"], "shares": [1]},
+                {"n": 0, "zipf_s": 1.0}):
+        with pytest.raises(ValueError):
+            traffic.tenants(dict(MIX, tenants=bad))

@@ -18,6 +18,11 @@ of the blocks, and draws the prompt tokens. So runs with different seeds
 offer the same load, also over any few seconds, and differ in its order;
 one seed always gives one schedule.
 
+A file may name ``tenants`` (ids with shares, or Zipf shares over ``n``);
+their labels are dealt in the same way, in exact proportions, from a
+stream of the seed's own, so the rest of the schedule is the same with or
+without them. Without the key every request is tenant ``t0``'s.
+
 This arrival process is ``stratified_exponential``: exponential gaps, but
 smoother than Poisson, since every ``BLOCK`` arrivals span about
 ``BLOCK / rate`` seconds and no cluster of arrivals or of long requests
@@ -36,6 +41,8 @@ import numpy as np
 BENCH = Path(__file__).resolve().parent
 ARRIVALS = "stratified_exponential"
 BLOCK = 10                # requests per block of the dealt mix
+DEFAULT_TENANT = "t0"
+TENANT_STREAM = 1         # the tenants' draws: a stream of their own
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,6 +51,7 @@ class Arrival:
     due_s: float          # seconds after the traffic starts
     prompt: np.ndarray    # (prompt_len,) int32
     max_new: int
+    tenant: str = DEFAULT_TENANT
 
 
 def load_traffic(name: str, root: Path = BENCH) -> dict:
@@ -59,15 +67,41 @@ def _quantiles(n: int) -> np.ndarray:
     return (np.arange(n) + 0.5) / n
 
 
-def prompt_lengths(traffic: dict, n: int) -> np.ndarray:
-    """The mixture's lengths in exact proportions (largest remainder)."""
-    lens = traffic["prompt"]["lengths"]
-    w = np.asarray(traffic["prompt"]["weights"], float)
+def _in_proportion(values, weights, n: int) -> np.ndarray:
+    """``n`` of ``values`` in the exact proportions of ``weights`` (largest
+    remainder)."""
+    w = np.asarray(weights, float)
     want = w / w.sum() * n
     counts = np.floor(want).astype(int)
     for i in np.argsort(-(want - counts), kind="stable")[:n - counts.sum()]:
         counts[i] += 1
-    return np.repeat(np.asarray(lens, int), counts)
+    return np.repeat(np.asarray(values, int), counts)
+
+
+def prompt_lengths(traffic: dict, n: int) -> np.ndarray:
+    """The mixture's lengths in exact proportions (largest remainder)."""
+    return _in_proportion(traffic["prompt"]["lengths"],
+                          traffic["prompt"]["weights"], n)
+
+
+def tenants(traffic: dict) -> dict:
+    """{tenant id: share of the requests}: the file's ``tenants``, either
+    ``ids`` with their ``shares`` or ``n`` tenants ``t0``.. with Zipf shares
+    of exponent ``zipf_s`` (tenant i's share in proportion to
+    1/(i+1)^s); without the key, one tenant ``t0`` sends everything."""
+    spec = traffic.get("tenants")
+    if spec is None:
+        return {DEFAULT_TENANT: 1.0}
+    if "zipf_s" in spec:
+        ids = [f"t{i}" for i in range(spec["n"])]
+        w = (np.arange(spec["n"]) + 1.0) ** -float(spec["zipf_s"])
+    else:
+        ids, w = list(spec["ids"]), np.asarray(spec["shares"], float)
+    if not ids or len(set(ids)) != len(ids) or len(w) != len(ids) \
+            or (w <= 0).any():
+        raise ValueError(f"tenants {spec!r}: need distinct ids, one "
+                         f"positive share each")
+    return dict(zip(ids, (w / w.sum()).tolist()))
 
 
 def output_lengths(traffic: dict, n: int) -> np.ndarray:
@@ -104,10 +138,14 @@ def schedule(traffic: dict, seed: int, seconds: float,
     lens = _dealt(prompt_lengths(traffic, n), BLOCK, rng)
     outs = _dealt(output_lengths(traffic, n), BLOCK, rng)
     due = np.cumsum(gaps) - gaps[0]
+    shares = tenants(traffic)
+    ids = list(shares)
+    who = _dealt(_in_proportion(range(len(ids)), list(shares.values()), n),
+                 BLOCK, np.random.default_rng([seed, TENANT_STREAM]))
     return [Arrival(uid=i, due_s=float(due[i]),
                     prompt=rng.integers(0, vocab_size, size=int(lens[i]),
                                         dtype=np.int32),
-                    max_new=int(outs[i]))
+                    max_new=int(outs[i]), tenant=ids[who[i]])
             for i in range(n)]
 
 
@@ -120,4 +158,6 @@ def summary(traffic: dict) -> str:
     return (f"rate {traffic['rate_per_s']}/s, prompts "
             f"{traffic['prompt']['lengths']} w {traffic['prompt']['weights']}"
             f", outputs {o['dist']} {o.get('median', '')} "
-            f"[{o['min']}, {o['max']}] mean {mean_output(traffic):.1f}")
+            f"[{o['min']}, {o['max']}] mean {mean_output(traffic):.1f}"
+            + (f", tenants {tenants(traffic)}" if "tenants" in traffic
+               else ""))

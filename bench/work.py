@@ -2,25 +2,30 @@
 
 These count what the algorithm requires, whatever implements it:
 
-* FLOPs: 2 per multiply-add of every weight a token uses (the attention
-  projections, the router and the k routed experts, or the dense MLP; the
-  LM head only where a logit is produced), plus causal attention
+* FLOPs: 2 per multiply-add of every weight a token uses (in a mixture of
+  experts the router and the k routed experts; the LM head only where a
+  logit is produced), plus attention's score and value products
   (2 x 2 x heads x head_dim per visible key).
 * Bytes of a decode step: every weight the step must read once (weights
-  in bf16, the router in f32), where a mixture of experts reads only the
+  in bf16, a router in f32), where a mixture of experts reads only the
   experts its B live tokens route to, E(1 - (1 - k/E)^B) in expectation
-  with uniform routing, and the K and V of every live position.
+  with uniform routing; the cache of every live position that attention
+  reads; and every live slot's fixed-size state.
 
+The architecture module of the configuration (``bench/archs/``) says which
+layers hold what; the block counts below are the vocabulary it counts in.
 Embedding lookups and norms are left out: they are a rounding error here.
 """
 from __future__ import annotations
 
 from typing import Iterable
 
+from bench import archs
+
 BF16, F32 = 2, 4
 
 
-def _dims(conf: dict):
+def dims(conf: dict):
     d, H, KV = (conf["hidden_size"], conf["num_attention_heads"],
                 conf["num_key_value_heads"])
     hd = int(conf.get("head_dim") or d // H)
@@ -28,7 +33,7 @@ def _dims(conf: dict):
 
 
 def attn_params(conf: dict) -> int:
-    d, H, KV, hd = _dims(conf)
+    d, H, KV, hd = dims(conf)
     return 2 * d * H * hd + 2 * d * KV * hd
 
 
@@ -42,7 +47,8 @@ def router_params(conf: dict) -> int:
 
 
 def active_layer_params(conf: dict) -> int:
-    """Weights one token multiplies by in one layer."""
+    """Weights one token multiplies by in one attention and SwiGLU (or
+    mixture of experts) layer."""
     k = conf.get("num_experts_per_tok", 1) if conf.get("num_local_experts") \
         else 1
     return attn_params(conf) + router_params(conf) + k * expert_params(conf)
@@ -50,27 +56,6 @@ def active_layer_params(conf: dict) -> int:
 
 def head_params(conf: dict) -> int:
     return conf["hidden_size"] * conf["vocab_size"]
-
-
-def kv_bytes_per_token(conf: dict) -> int:
-    _, _, KV, hd = _dims(conf)
-    return 2 * conf["num_hidden_layers"] * KV * hd * BF16
-
-
-def attention_flops(conf: dict, visible_keys: int) -> int:
-    """Score and value products of one query row over ``visible_keys``,
-    across every layer."""
-    _, H, _, hd = _dims(conf)
-    return 4 * H * hd * visible_keys * conf["num_hidden_layers"]
-
-
-def prefill_flops(conf: dict, prompt_len: int) -> int:
-    """One prompt of ``prompt_len`` tokens, causal, with the logits of its
-    last position."""
-    T, L = prompt_len, conf["num_hidden_layers"]
-    return (2 * active_layer_params(conf) * L * T
-            + attention_flops(conf, T * (T + 1) // 2)
-            + 2 * head_params(conf))
 
 
 def expected_experts(conf: dict, tokens: int) -> float:
@@ -82,27 +67,46 @@ def expected_experts(conf: dict, tokens: int) -> float:
     return e * (1.0 - (1.0 - k / e) ** tokens)
 
 
+def kv_bytes_per_token(conf: dict) -> int:
+    """The cache one position adds, over every layer."""
+    return archs.of(conf).kv_bytes_per_token(conf)
+
+
+def attention_flops(conf: dict, positions: Iterable[int]) -> int:
+    """Score and value products of query rows at ``positions``, across
+    every layer."""
+    return archs.of(conf).attention_flops(conf, positions)
+
+
+def prefill_flops(conf: dict, prompt_len: int) -> int:
+    """One prompt of ``prompt_len`` tokens, causal, with the logits of its
+    last position."""
+    arch = archs.of(conf)
+    return (sum(arch.layer_flops(conf)) * prompt_len
+            + arch.attention_flops(conf, range(prompt_len))
+            + 2 * head_params(conf))
+
+
 def decode_flops(conf: dict, positions: Iterable[int]) -> int:
     """One decode step; ``positions`` holds each live slot's position
     (the new token's index; it attends to ``position + 1`` keys)."""
     pos = list(positions)
-    L = conf["num_hidden_layers"]
-    return (2 * active_layer_params(conf) * L * len(pos)
-            + attention_flops(conf, sum(p + 1 for p in pos))
+    arch = archs.of(conf)
+    return (sum(arch.layer_flops(conf)) * len(pos)
+            + arch.attention_flops(conf, pos)
             + 2 * head_params(conf) * len(pos))
 
 
 def decode_bytes(conf: dict, positions: Iterable[int]) -> float:
-    """Bytes one decode step must move: weights read once, KV of every
-    live position read."""
+    """Bytes one decode step must move: weights read once, the cache of
+    every live position read, every live slot's state read and written."""
     pos = list(positions)
     if not pos:
         return 0.0
-    L = conf["num_hidden_layers"]
-    weights = (attn_params(conf) * BF16 + router_params(conf) * F32
-               + expected_experts(conf, len(pos)) * expert_params(conf) * BF16)
-    return (L * weights + head_params(conf) * BF16
-            + kv_bytes_per_token(conf) * sum(p + 1 for p in pos))
+    arch = archs.of(conf)
+    return (arch.decode_weight_bytes(conf, len(pos))
+            + arch.kv_bytes(conf, pos)
+            + 2 * arch.state_bytes_per_slot(conf) * len(pos))
 
 
 def bound_seconds(flops: float, nbytes: float, peaks: dict) -> float:
